@@ -109,4 +109,6 @@ def main(argv=None) -> None:
 
 
 if __name__ == "__main__":
+    import repro
+    repro.use_compile_cache()
     main()

@@ -29,9 +29,9 @@ PR appends to.  Timings use the SECOND `run_experiment` call (zero
 compiles, steady state); compile time is reported separately from the
 first call.  A `kernels` section times the `repro.kernels.netsim`
 `cycle_core` Pallas kernel standalone: interpret-mode ms/call on every
-backend, plus a compiled (non-interpret) attempt that records
-`supported: false` with the error on backends (CPU) whose Pallas
-lowering only interprets.
+backend, plus a compiled (non-interpret) attempt.  On the TPU a failed
+compiled attempt is an error; only on CPU, whose Pallas lowering only
+interprets, is it recorded as `supported: false` with the error.
 
 The bench_sweep and fig11 points double as the PERF-REGRESSION GUARD:
 when a previous BENCH_perf.json of the same mode exists and either
@@ -43,16 +43,15 @@ them.  Every scenario record also carries the compact-step telemetry
 so the trajectory documents how much of each ladder rung the workload
 actually used.
 
-Unless already set in the environment, this benchmark defaults the two
-engine perf knobs to their tuned values — `REPRO_HOST_DEVICES=4` (shard
-lanes over 4 forced host devices) and `REPRO_CPU_RUNTIME=legacy` (the
-pre-thunk XLA:CPU runtime, ~4x faster on this small-op scan; see
-docs/performance.md) — and records both in the output, so the committed
-file documents exactly how it was produced.  Export either knob to
+On the CPU backend, unless already set in the environment, this
+benchmark shards lanes over 4 forced host devices
+(`REPRO_HOST_DEVICES=4`) and records the knob in the output, so the
+committed file documents exactly how it was produced.  Export it to
 override (e.g. `REPRO_HOST_DEVICES=1` for a single-device trajectory
-point).  `--fast` trims the heavy scenarios' cycle budgets for CI smoke
-runs; cycles/s stays comparable per (scenario, mode) pair, and
-`speedup_vs_previous` only compares matching modes.
+point); other backends use their real devices.  `--fast` trims the
+heavy scenarios' cycle budgets for CI smoke runs; cycles/s stays
+comparable per (scenario, mode) pair, and `speedup_vs_previous` only
+compares matching modes.
 
     python -m benchmarks.bench_perf
     python -m benchmarks.bench_perf --fast          (CI perf-smoke)
@@ -226,7 +225,8 @@ def _bench_kernels(fast: bool) -> dict:
     synthetic fused-step-shaped inputs: interpret-mode ms/call, plus a
     compiled (non-interpret) attempt.  On CPU the Pallas lowering only
     interprets, so the compiled record documents `supported: false` with
-    the error; on TPU it carries the real compiled timing."""
+    the error; on TPU it carries the real compiled timing, and a failed
+    compile is an error."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -257,28 +257,12 @@ def _bench_kernels(fast: bool) -> dict:
     try:
         rec["compiled"] = dict(supported=True, ms_per_call=timed(False))
     except Exception as e:
+        if jax.default_backend() == "tpu":
+            raise
         rec["compiled"] = dict(
             supported=False,
             error=f"{type(e).__name__}: {str(e)[:200]}")
     return {"netsim_cycle_core": rec}
-
-
-def _legacy_runtime_supported() -> bool:
-    """Probe whether this jaxlib still accepts the legacy-CPU-runtime
-    flag.  Must run in a SUBPROCESS: XLA parses XLA_FLAGS at backend
-    init and dies on unknown flags, so probing in-process would take the
-    benchmark down with it on builds that dropped the flag."""
-    import subprocess
-    import sys
-    probe = ("import os; "
-             "os.environ['XLA_FLAGS'] = '--xla_cpu_use_thunk_runtime=false'; "
-             "import jax; jax.devices()")
-    try:
-        return subprocess.run([sys.executable, "-c", probe],
-                              capture_output=True,
-                              timeout=300).returncode == 0
-    except Exception:
-        return False
 
 
 def _previous(path: str) -> dict:
@@ -323,7 +307,6 @@ def bench(fast: bool = False, out_path: str = DEFAULT_OUT) -> dict:
         mode="fast" if fast else "full",
         device_count=len(jax.devices()),
         repro_host_devices=os.environ.get("REPRO_HOST_DEVICES"),
-        repro_cpu_runtime=os.environ.get("REPRO_CPU_RUNTIME"),
         scenarios=scenarios,
         kernels=kernels,
         provenance=provenance(),
@@ -342,15 +325,13 @@ def main(argv=None) -> int:
                          "previous BENCH_perf.json) instead of exiting "
                          "nonzero")
     args = ap.parse_args(argv)
-    # tuned defaults, recorded in the output; env overrides.  Must happen
-    # before the first repro/jax import (the knobs set XLA_FLAGS).  The
-    # legacy runtime is only defaulted on when the installed jaxlib still
-    # accepts the flag, so an unpinned-jax CI job degrades to the default
-    # runtime instead of dying at backend init.
-    os.environ.setdefault("REPRO_HOST_DEVICES", "4")
-    if "REPRO_CPU_RUNTIME" not in os.environ and _legacy_runtime_supported():
-        os.environ["REPRO_CPU_RUNTIME"] = "legacy"
-    import repro  # noqa: F401  (applies the knobs before jax init)
+    # the CPU default, recorded in the output; env overrides.  Must happen
+    # before the first repro/jax import (the knob sets XLA_FLAGS), so the
+    # platform is read from JAX_PLATFORMS, not from a JAX backend
+    if os.environ.get("JAX_PLATFORMS", "").split(",")[0] == "cpu":
+        os.environ.setdefault("REPRO_HOST_DEVICES", "4")
+    import repro  # applies the knob before jax init
+    repro.use_compile_cache()
     path = os.path.abspath(args.out or DEFAULT_OUT)
     out = bench(fast=args.fast, out_path=path)
     with open(path, "w") as f:

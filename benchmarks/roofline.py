@@ -247,4 +247,6 @@ def main(argv=None):
 
 
 if __name__ == "__main__":
+    import repro
+    repro.use_compile_cache()
     main()

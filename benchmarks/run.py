@@ -128,4 +128,6 @@ def main() -> None:
 
 
 if __name__ == "__main__":
+    import repro
+    repro.use_compile_cache()
     main()

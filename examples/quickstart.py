@@ -58,4 +58,6 @@ def main():
 
 
 if __name__ == "__main__":
+    import repro
+    repro.use_compile_cache()
     main()

@@ -12,16 +12,12 @@ to round-robin independent grid cells.  Unset (the default) leaves JAX's
 single-CPU-device behavior untouched; real multi-device backends (TPU)
 need no flag and shard automatically.
 
-REPRO_CPU_RUNTIME=legacy (opt-in) selects XLA:CPU's pre-thunk runtime
-(`--xla_cpu_use_thunk_runtime=false`).  The engine's cycle loop is a
-long scan of many small ops, which is exactly the shape the thunk
-runtime's per-op dispatch overhead hurts most — on the bench_sweep grid
-the legacy runtime is ~4x faster (see docs/performance.md and
-BENCH_perf.json).  Results are bit-identical either way (same compiled
-HLO, different executor).  Opt-in because the flag may not exist on
-every XLA build; "thunks" explicitly keeps the default runtime.
+That knob must be read BEFORE the backend exists, hence this module.
 
-Both knobs must be read BEFORE the backend exists, hence this module.
+`use_compile_cache()` places JAX's persistent compilation cache for the
+entry points (`chip_smoke.py`, `python -m repro.exp.run`, the
+benchmarks and examples).  It is not called on import, so importing
+the library (the test suite does) writes nothing into the checkout.
 
 This module is also the ONLY place the library reads environment
 variables (`repro.analysis` lint rule REPRO002): every other `REPRO_*`
@@ -78,12 +74,6 @@ def _flag_setup() -> None:
         if count < 1:
             raise ValueError(f"REPRO_HOST_DEVICES={count} must be >= 1")
         add.append(f"--xla_force_host_platform_device_count={count}")
-    runtime = os.environ.get("REPRO_CPU_RUNTIME")
-    if runtime not in (None, "", "legacy", "thunks"):
-        raise ValueError(
-            f"REPRO_CPU_RUNTIME={runtime!r} must be 'legacy' or 'thunks'")
-    if runtime == "legacy":
-        add.append("--xla_cpu_use_thunk_runtime=false")
     if not add:
         return
     flags = os.environ.get("XLA_FLAGS", "")
@@ -95,10 +85,27 @@ def _flag_setup() -> None:
         # jax may already have initialized its backend, in which case the
         # flags below are read too late and silently do nothing
         warnings.warn(
-            "REPRO_HOST_DEVICES/REPRO_CPU_RUNTIME set but jax was "
-            "imported before repro; the flags may not take effect",
+            "REPRO_HOST_DEVICES set but jax was imported before repro; "
+            "the flag may not take effect",
             RuntimeWarning, stacklevel=3)
     os.environ["XLA_FLAGS"] = " ".join([flags] + add).strip()
 
 
+def use_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    When `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads it and
+    nothing is overridden.  Otherwise the cache goes to the fixed path
+    `<checkout>/.jax_cache` (git-ignored): a fixed path, because the
+    directory is part of what a later run must find again."""
+    import jax
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = os.path.join(_CHECKOUT, ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 _flag_setup()

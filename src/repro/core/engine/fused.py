@@ -74,10 +74,12 @@ channel/terminal counts are padded with ghost entries (dead, never
 eligible, zero stats).
 
 `cfg.grant_impl="pallas"` routes the grant reduction of the UNSHARDED
-fused step through the `repro.kernels.netsim` `cycle_core` Pallas kernel
-(interpret mode on CPU, compiled on TPU); the sharded variant always
-uses the jnp segment-min partials, because the global minimum only
-exists after the `pmin` exchange.
+fused and compact steps through the `repro.kernels.netsim` `cycle_core`
+Pallas kernel (interpret mode on CPU, compiled on TPU).  The kernel
+needs the packed key, and the sharded variant's global minimum only
+exists after the `pmin` exchange, so a requested kernel raises under
+the two-pass form or channel sharding instead of being replaced by the
+jnp segment-min.
 """
 from __future__ import annotations
 
@@ -220,6 +222,20 @@ def _grant(ok, out, itime, prio, ch_ok, E, R2, use_combined):
     return won_ch, jnp.where(won_ch, m2, 0)
 
 
+def _use_pallas(cfg, use_combined: bool) -> bool:
+    """Whether the step grants through the `cycle_core` kernel.  The
+    kernel needs the packed key, so a requested ``grant_impl="pallas"``
+    under the two-pass form is an error, never a silent `segment_min`."""
+    if getattr(cfg, "grant_impl", "jnp") != "pallas":
+        return False
+    if not use_combined:
+        raise ValueError(
+            "grant_impl='pallas' needs the packed grant key, but "
+            f"{cfg.warmup + cfg.measure} cycles overflow it (grant_form "
+            "'two_pass'); shorten the run or use grant_impl='jnp'")
+    return True
+
+
 def compact_rows(net: Network, cfg) -> int:
     """N, the unsharded request-row count (`E_req * NV + T`) — the
     compact step's capacity ladder is sized against this."""
@@ -316,8 +332,7 @@ def _make_compact(net, cfg, pattern, inject_mask, consts, route_kernel,
     N = ER * NV + T
     R2 = _pow2(N)
     use_combined = grant_form(net, cfg) == "combined"
-    use_pallas = getattr(cfg, "grant_impl", "jnp") == "pallas" \
-        and use_combined
+    use_pallas = _use_pallas(cfg, use_combined)
     if use_pallas:
         from ...kernels.netsim.ops import cycle_core
     reap_age = resolve_reap_age(cfg)   # 0 = reaper off (trace-time)
@@ -520,8 +535,7 @@ def _make_unsharded(net, cfg, pattern, inject_mask, consts, route_kernel):
     # (`grant_form` is the shared predicate; the chosen form is surfaced
     # in `SweepResult.grant_form` and checked statically by the spec pass)
     use_combined = grant_form(net, cfg) == "combined"
-    use_pallas = getattr(cfg, "grant_impl", "jnp") == "pallas" \
-        and use_combined
+    use_pallas = _use_pallas(cfg, use_combined)
     if use_pallas:
         from ...kernels.netsim.ops import cycle_core
     reap_age = resolve_reap_age(cfg)   # 0 = reaper off (trace-time)
@@ -693,6 +707,10 @@ def _make_sharded(net, cfg, pattern, inject_mask, consts, route_kernel,
     its shard index, keeps the rest of the state replicated, and
     exchanges the per-channel grant minima (`pmin`) + winner records
     (`psum`) at the phase boundary."""
+    if getattr(cfg, "grant_impl", "jnp") == "pallas":
+        raise ValueError(
+            "grant_impl='pallas' has no channel-sharded form; unset "
+            "REPRO_CHANNEL_SHARDS or use grant_impl='jnp'")
     NV, E, T = consts["NV"], consts["E"], consts["T"]
     S, Q = cfg.buf_pkts, cfg.srcq_pkts
     vpc = cfg.vcs_per_class

@@ -68,7 +68,6 @@ cells across devices (see `repro.exp.runner`).
 from __future__ import annotations
 
 import functools
-import inspect
 import time
 from dataclasses import dataclass
 from typing import NamedTuple
@@ -76,19 +75,8 @@ from typing import NamedTuple
 import numpy as np
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
-
-# shard_map moved out of jax.experimental (and its replication-check
-# kwarg was renamed) across JAX releases; resolve whichever this
-# installation has so the engine imports everywhere.
-try:
-    from jax import shard_map as _shard_map          # modern JAX
-except ImportError:                                  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-_SHMAP_PARAMS = inspect.signature(_shard_map).parameters
-_SHMAP_NOCHECK = ({"check_rep": False} if "check_rep" in _SHMAP_PARAMS
-                  else {"check_vma": False} if "check_vma" in _SHMAP_PARAMS
-                  else {})
 
 from ... import env_int
 from ..topology import (FaultSchedule, FaultSet, Network, as_fault_schedule,
@@ -327,10 +315,10 @@ def _make_dispatch_fn(step, cycles, reset_at, per_lane_faults, mesh,
         if state_spec is None:
             state_spec = lane_spec
         data_spec = lane_spec if per_lane_faults else PartitionSpec()
-        f = _shard_map(f, mesh=mesh,
+        f = shard_map(f, mesh=mesh,
                        in_specs=(state_spec, lane_spec, lane_spec,
                                  data_spec),
-                       out_specs=state_spec, **_SHMAP_NOCHECK)
+                       out_specs=state_spec, check_vma=False)
     return jax.jit(f, donate_argnums=(0,))
 
 
@@ -394,10 +382,10 @@ def _make_window_fn(step, window, reset_at, per_lane_faults, mesh, K=1):
         lane_spec = PartitionSpec("lanes")
         scal_spec = PartitionSpec()
         data_spec = lane_spec if per_lane_faults else scal_spec
-        f = _shard_map(f, mesh=mesh,
+        f = shard_map(f, mesh=mesh,
                        in_specs=(lane_spec, lane_spec, scal_spec,
                                  scal_spec, lane_spec, data_spec),
-                       out_specs=(lane_spec, lane_spec), **_SHMAP_NOCHECK)
+                       out_specs=(lane_spec, lane_spec), check_vma=False)
     return jax.jit(f, donate_argnums=(0, 1))
 
 

@@ -1,10 +1,11 @@
 """Provenance records for benchmark artifacts.
 
 Every BENCH_*.json this repo writes carries a `provenance` block — the
-git revision, JAX version, backend platform, and the SHA-256 of the
-serialized `ExperimentSpec` that produced the numbers — so a benchmark
-file is attributable to an exact code + spec + backend triple without
-relying on the commit that happened to check it in.
+git revision, JAX version, backend platform, device kind and count,
+and the SHA-256 of the serialized `ExperimentSpec` that produced the
+numbers — so a benchmark file is attributable to an exact code + spec +
+device triple without relying on the commit that happened to check it
+in.
 """
 from __future__ import annotations
 
@@ -46,12 +47,15 @@ def provenance(spec=None) -> dict:
     """The provenance block benchmarks embed in their BENCH_*.json."""
     import jax
     rev, dirty = git_revision()
+    devs = jax.devices()
     out = dict(
         git_rev=rev,
         dirty=dirty,
         jax_version=jax.__version__,
         backend=jax.default_backend(),
-        platform=jax.devices()[0].platform,
+        platform=devs[0].platform,
+        device_kind=devs[0].device_kind,
+        device_count=len(devs),
     )
     if spec is not None:
         out["spec_sha256"] = spec_hash(spec)

@@ -166,4 +166,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from .. import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

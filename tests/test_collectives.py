@@ -11,11 +11,8 @@ _BODY = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map          # jax >= 0.5
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     from repro.core import collectives as C
 
     mesh = jax.make_mesh((2, 4), ("data", "model"))
