@@ -1,0 +1,9 @@
+"""device_ms_per_lane_cycle: device busy time in the traced windows (the
+union of the device's op intervals) over the lane-cycles those windows
+simulated.  Layer: cycle step.  Moves lane_cycles_per_s."""
+
+
+def read(run, trace):
+    if not trace or not run.get("lane_cycles_traced") or trace["busy_s"] <= 0:
+        return None
+    return 1e3 * trace["busy_s"] / run["lane_cycles_traced"]
