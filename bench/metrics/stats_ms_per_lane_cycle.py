@@ -1,0 +1,9 @@
+"""stats_ms_per_lane_cycle: device time of the window executable's ops that
+the program scopes `cycle.stats` (counters, occupancy census, reaper
+mask, warm-up reset), over the lane-cycles of the traced windows. Layer:
+cycle step. Moves lane_cycles_per_s."""
+from bench import program
+
+
+def read(run, trace):
+    return program.ms_per_lane_cycle("stats", run, trace)

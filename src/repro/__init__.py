@@ -97,8 +97,14 @@ def use_compile_cache() -> str:
     When `JAX_COMPILATION_CACHE_DIR` is set, JAX already reads it and
     nothing is overridden.  Otherwise the cache goes to the fixed path
     `<checkout>/.jax_cache` (git-ignored): a fixed path, because the
-    directory is part of what a later run must find again."""
+    directory is part of what a later run must find again.
+
+    The cache key includes each op's metadata, which carries the cycle's
+    phase scopes (`repro.core.spans`): JAX's default key strips it, and
+    would load an executable compiled without the scopes, or with other
+    ones, in place of this build's."""
     import jax
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not path:
         path = os.path.join(_CHECKOUT, ".jax_cache")

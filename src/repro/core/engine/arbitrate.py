@@ -14,6 +14,7 @@ from dataclasses import dataclass, replace
 import jax
 import jax.numpy as jnp
 
+from ..spans import phase
 from ..topology import EJECT, Network
 from .state import (F_DEST, F_ITIME, F_META, F_MIS, F_READY, INF32,
                     SimState)
@@ -170,9 +171,11 @@ def make_arbitrate_fn(net: Network, cfg, consts, route_kernel):
                          f"valid: {GRANT_IMPLS}")
 
     def arbitrate(state, t, fl):
-        req = gather_requests(state, consts, route_kernel, fl, t)
-        req = expand_vcs(req, state, cfg)
-        win, won_ch = grant_fn(req, state, fl["ch_alive"])
+        with phase("route"):
+            req = gather_requests(state, consts, route_kernel, fl, t)
+            req = expand_vcs(req, state, cfg)
+        with phase("grant"):
+            win, won_ch = grant_fn(req, state, fl["ch_alive"])
         return req, win, won_ch
 
     return arbitrate

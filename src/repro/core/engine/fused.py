@@ -88,6 +88,7 @@ import jax
 import jax.numpy as jnp
 
 from ... import env_int
+from ..spans import phase
 from ..topology import EJECT, NUM_CH_TYPES, Network
 from ..traffic import as_pattern
 from .inject import make_inject_fn, make_misroute_fn
@@ -354,7 +355,8 @@ def _make_compact(net, cfg, pattern, inject_mask, consts, route_kernel,
         t, key, rate_pkt, fl = t_key_rate_fl
         cached = not is_scheduled(fl)   # trace-time, as in the fused step
         fl = resolve_epoch(fl, t)
-        state = inject(state, t, key, rate_pkt, fl)
+        with phase("inject"):
+            state = inject(state, t, key, rate_pkt, fl)
 
         # live-row census + stable compaction.  `occ` is EXACT (dense,
         # independent of C) — it feeds the occ_peak certificate the
@@ -368,152 +370,161 @@ def _make_compact(net, cfg, pattern, inject_mask, consts, route_kernel,
         # but they take the mesh path).  Slots past the live count keep
         # the N sentinel, so `aid` stays sorted (stable compaction
         # preserves row order) for the winner-slot search below.
-        lb = (state.b_count[:ER] > 0).astype(jnp.int32)     # [ER, NV]
-        within = jnp.cumsum(lb, axis=-1)
-        ch_tot = within[:, -1]
-        base = jnp.cumsum(ch_tot)                           # [ER]
-        scs = jnp.cumsum((state.s_count > 0).astype(jnp.int32))
-        occ = base[-1] + scs[-1]
-        cs = jnp.concatenate(
-            [((base - ch_tot)[:, None] + within).reshape(-1),
-             base[-1] + scs])                               # [N]
-        live = jnp.concatenate(
-            [lb.reshape(-1) > 0, state.s_count > 0])        # [N]
-        aid = jnp.full((C,), N, jnp.int32).at[
-            jnp.where(live, cs - 1, C)].set(row_iota, mode="drop")  # [C]
-        slot_ok = slot_iota < jnp.minimum(occ, C)
+        with phase("compact"):
+            lb = (state.b_count[:ER] > 0).astype(jnp.int32)     # [ER, NV]
+            within = jnp.cumsum(lb, axis=-1)
+            ch_tot = within[:, -1]
+            base = jnp.cumsum(ch_tot)                           # [ER]
+            scs = jnp.cumsum((state.s_count > 0).astype(jnp.int32))
+            occ = base[-1] + scs[-1]
+            cs = jnp.concatenate(
+                [((base - ch_tot)[:, None] + within).reshape(-1),
+                 base[-1] + scs])                               # [N]
+            live = jnp.concatenate(
+                [lb.reshape(-1) > 0, state.s_count > 0])        # [N]
+            aid = jnp.full((C,), N, jnp.int32).at[
+                jnp.where(live, cs - 1, C)].set(row_iota, mode="drop")  # [C]
+            slot_ok = slot_iota < jnp.minimum(occ, C)
 
         # per-slot request assembly: ONE C-row head gather (the fused
         # step's ER*NV-row gather, shrunk to the live set) + one C-row
         # source-queue gather, merged by slot kind
-        is_buf = aid < ER * NV
-        e = jnp.clip(aid // NV, 0, ER - 1)
-        v = jnp.clip(aid, 0, ER * NV - 1) % NV
-        tt = jnp.clip(aid - ER * NV, 0, T - 1)
-        bh = state.b_head[(e, v)]                            # [C]
-        brec = state.b_pkt[(e, v, bh)]                       # [C, 8]
-        srec = state.s_pkt[(tt, state.s_head[tt])]           # [C, 3]
-        ready = ~is_buf | (brec[:, F_READY] <= t)
-        valid = slot_ok & ready
-        if cached:
-            out_b, cls_b, meta2_b = (brec[:, F_OUT], brec[:, F_CLS],
-                                     brec[:, F_META2])
-        else:
-            out_b, cls_b, meta2_b = route_kernel(
-                fl, ch_dst[e], brec[:, F_DEST], brec[:, F_MIS],
-                brec[:, F_META])
-        out = jnp.where(is_buf, out_b, inject_ch[tt]).astype(jnp.int32)
-        cls = jnp.where(is_buf, cls_b, 0).astype(jnp.int32)
-        itime = jnp.where(is_buf, brec[:, F_ITIME], srec[:, F_ITIME])
-        dest = jnp.where(is_buf, brec[:, F_DEST], srec[:, F_DEST])
-        mis = jnp.where(is_buf, brec[:, F_MIS], srec[:, F_MIS])
-        meta2 = jnp.where(is_buf, meta2_b, 0).astype(jnp.int32)
-        rowok = valid & (out >= 0)
+        with phase("route"):
+            is_buf = aid < ER * NV
+            e = jnp.clip(aid // NV, 0, ER - 1)
+            v = jnp.clip(aid, 0, ER * NV - 1) % NV
+            tt = jnp.clip(aid - ER * NV, 0, T - 1)
+            bh = state.b_head[(e, v)]                            # [C]
+            brec = state.b_pkt[(e, v, bh)]                       # [C, 8]
+            srec = state.s_pkt[(tt, state.s_head[tt])]           # [C, 3]
+            ready = ~is_buf | (brec[:, F_READY] <= t)
+            valid = slot_ok & ready
+            if cached:
+                out_b, cls_b, meta2_b = (brec[:, F_OUT], brec[:, F_CLS],
+                                         brec[:, F_META2])
+            else:
+                out_b, cls_b, meta2_b = route_kernel(
+                    fl, ch_dst[e], brec[:, F_DEST], brec[:, F_MIS],
+                    brec[:, F_META])
+            out = jnp.where(is_buf, out_b, inject_ch[tt]).astype(jnp.int32)
+            cls = jnp.where(is_buf, cls_b, 0).astype(jnp.int32)
+            itime = jnp.where(is_buf, brec[:, F_ITIME], srec[:, F_ITIME])
+            dest = jnp.where(is_buf, brec[:, F_DEST], srec[:, F_DEST])
+            mis = jnp.where(is_buf, brec[:, F_MIS], srec[:, F_MIS])
+            meta2 = jnp.where(is_buf, meta2_b, 0).astype(jnp.int32)
+            rowok = valid & (out >= 0)
         # router-death reaper over the active rows: undeliverable rows
         # (parked on -1 OR requesting a dead channel — see
         # stats.undeliverable_mask) are live, so whenever occ <= C they
         # are ALL in the active set — the reap mask is exact under the
         # same occ_peak certificate that covers the grant
-        if reap_age:
-            undel = valid & ((out < 0)
-                             | ~fl["ch_alive"][jnp.clip(out, 0, E - 1)])
-            reap = undel & (t - itime >= reap_age)
-        else:
-            undel = reap = None
+        with phase("stats"):
+            if reap_age:
+                undel = valid & ((out < 0)
+                                 | ~fl["ch_alive"][jnp.clip(out, 0, E - 1)])
+                reap = undel & (t - itime >= reap_age)
+            else:
+                undel = reap = None
         prio = aid      # the global row id IS the oracle's tie-break
 
         # grant over the C active rows — same segments, same packed
         # keys, same winners as the fused step's N-row reduction
-        occ_min, occ_arg = _occ_tables(state.b_count, NC, vpc)
-        elig_ck = (occ_min < S) | is_ej_ch[:, None]
-        ok = rowok & _row_elig(elig_ck, out, cls, E)
-        ch_ok = (state.ch_busy == 0) & fl["ch_alive"]
-        if use_pallas:
-            won_ch, wprio, win_slot = cycle_core(out, itime, ok, ch_ok,
-                                                 r2=R2, prio=prio)
-        else:
-            won_ch, wprio = _grant(ok, out, itime, prio, ch_ok, E, R2,
-                                   use_combined)
-            win_slot = None
+        with phase("route"):
+            occ_min, occ_arg = _occ_tables(state.b_count, NC, vpc)
+        with phase("grant"):
+            elig_ck = (occ_min < S) | is_ej_ch[:, None]
+            ok = rowok & _row_elig(elig_ck, out, cls, E)
+            ch_ok = (state.ch_busy == 0) & fl["ch_alive"]
+            if use_pallas:
+                won_ch, wprio, win_slot = cycle_core(out, itime, ok, ch_ok,
+                                                     r2=R2, prio=prio)
+            else:
+                won_ch, wprio = _grant(ok, out, itime, prio, ch_ok, E, R2,
+                                       use_combined)
+                win_slot = None
 
         # dense winner table: map each granting channel's winning row
         # id back to its active slot (aid is sorted, so one binary
         # search), then ONE [E, 5]-gather of the compacted records
-        wslot_i = jnp.clip(
-            jnp.searchsorted(aid, wprio, side="left"), 0, C - 1)
-        crec = jnp.stack([dest, itime, mis, meta2, cls], axis=-1)
-        w = crec[wslot_i]                                     # [E, 5]
-        wdest, witime = w[:, W_DEST], w[:, W_ITIME]
-        wmis, wmeta, wcls = w[:, W_MIS], w[:, W_META], w[:, W_CLS]
-        wvc, wovc = _winner_vc(wcls, occ_min, occ_arg, NC, vpc)
-        entered = (wmis >= 0) & (ch_dst_wg == wmis)
-        wmis = jnp.where(entered, -1, wmis)
-        push = won_ch & ~is_ej_ch
-        whead = state.b_head[(ch_iota, jnp.clip(wvc, 0, NV - 1))]
-        wslot = (whead + wovc) % S
+        with phase("apply"):
+            wslot_i = jnp.clip(
+                jnp.searchsorted(aid, wprio, side="left"), 0, C - 1)
+            crec = jnp.stack([dest, itime, mis, meta2, cls], axis=-1)
+            w = crec[wslot_i]                                     # [E, 5]
+            wdest, witime = w[:, W_DEST], w[:, W_ITIME]
+            wmis, wmeta, wcls = w[:, W_MIS], w[:, W_META], w[:, W_CLS]
+            wvc, wovc = _winner_vc(wcls, occ_min, occ_arg, NC, vpc)
+            entered = (wmis >= 0) & (ch_dst_wg == wmis)
+            wmis = jnp.where(entered, -1, wmis)
+            push = won_ch & ~is_ej_ch
+            whead = state.b_head[(ch_iota, jnp.clip(wvc, 0, NV - 1))]
+            wslot = (whead + wovc) % S
         if cached:
-            out2, cls2, meta2_n = route_kernel(fl, ch_dst, wdest, wmis,
-                                               wmeta)
-            tail = [out2.astype(jnp.int32), cls2.astype(jnp.int32),
-                    meta2_n.astype(jnp.int32)]
+            with phase("route"):
+                out2, cls2, meta2_n = route_kernel(fl, ch_dst, wdest, wmis,
+                                                   wmeta)
+                tail = [out2.astype(jnp.int32), cls2.astype(jnp.int32),
+                        meta2_n.astype(jnp.int32)]
         else:
             z = jnp.zeros_like(wdest)
             tail = [z, z, z]
-        new_rec = jnp.stack(
-            [wdest, witime, wmis, wmeta, t + ch_lat] + tail, axis=-1)
-        pe = jnp.where(push, ch_iota, E)
-        b_pkt = state.b_pkt.at[(pe, wvc, wslot)].set(new_rec,
-                                                     mode="drop")
+        with phase("apply"):
+            new_rec = jnp.stack(
+                [wdest, witime, wmis, wmeta, t + ch_lat] + tail, axis=-1)
+            pe = jnp.where(push, ch_iota, E)
+            b_pkt = state.b_pkt.at[(pe, wvc, wslot)].set(new_rec,
+                                                         mode="drop")
 
-        # pops: the fused step's N-row gather+compare shrinks to C; the
-        # per-(channel, vc) / per-terminal pop bookkeeping stays in the
-        # dense one-hot form — XLA:CPU vectorizes the [E, NV] rebuilds
-        # well, while the equivalent scatter chains lower to slow
-        # row-at-a-time loops (measured ~2x worse)
-        if win_slot is None:
-            wprio_eff = jnp.where(won_ch, wprio, -1)
-            won_slot = rowok & (wprio_eff[jnp.clip(out, 0, E - 1)]
-                                == aid)
-        else:
-            won_slot = win_slot
-        # reaped rows pop like winners but push nowhere (masks disjoint:
-        # a winner's out channel is live, a reap victim's is -1 or
-        # dead); source rows are reapable too — a source head whose
-        # injection channel died can never be granted
-        pop_slot = won_slot if reap is None else won_slot | reap
-        pe_b = jnp.where(pop_slot & is_buf, e, E)
-        pop1 = jnp.zeros((E, NV), jnp.int32).at[(pe_b, v)].add(
-            1, mode="drop")
-        b_head = (state.b_head + pop1) % S
-        vc_oh = wvc[:, None] == vc_iota[None, :]
-        b_count = (state.b_count - pop1
-                   + (push[:, None] & vc_oh).astype(jnp.int32))
-        ts_m = jnp.where(pop_slot & ~is_buf, tt, T)
-        pop_s = jnp.zeros((T,), jnp.int32).at[ts_m].add(1, mode="drop")
-        s_head = (state.s_head + pop_s) % Q
-        s_count = state.s_count - pop_s
-        ch_busy = jnp.where(won_ch, ch_ser - 1,
-                            jnp.maximum(state.ch_busy - 1, 0))
+            # pops: the fused step's N-row gather+compare shrinks to C; the
+            # per-(channel, vc) / per-terminal pop bookkeeping stays in the
+            # dense one-hot form — XLA:CPU vectorizes the [E, NV] rebuilds
+            # well, while the equivalent scatter chains lower to slow
+            # row-at-a-time loops (measured ~2x worse)
+            if win_slot is None:
+                wprio_eff = jnp.where(won_ch, wprio, -1)
+                won_slot = rowok & (wprio_eff[jnp.clip(out, 0, E - 1)]
+                                    == aid)
+            else:
+                won_slot = win_slot
+            # reaped rows pop like winners but push nowhere (masks disjoint:
+            # a winner's out channel is live, a reap victim's is -1 or
+            # dead); source rows are reapable too — a source head whose
+            # injection channel died can never be granted
+            pop_slot = won_slot if reap is None else won_slot | reap
+            pe_b = jnp.where(pop_slot & is_buf, e, E)
+            pop1 = jnp.zeros((E, NV), jnp.int32).at[(pe_b, v)].add(
+                1, mode="drop")
+            b_head = (state.b_head + pop1) % S
+            vc_oh = wvc[:, None] == vc_iota[None, :]
+            b_count = (state.b_count - pop1
+                       + (push[:, None] & vc_oh).astype(jnp.int32))
+            ts_m = jnp.where(pop_slot & ~is_buf, tt, T)
+            pop_s = jnp.zeros((T,), jnp.int32).at[ts_m].add(1, mode="drop")
+            s_head = (state.s_head + pop_s) % Q
+            s_count = state.s_count - pop_s
+            ch_busy = jnp.where(won_ch, ch_ser - 1,
+                                jnp.maximum(state.ch_busy - 1, 0))
 
         # stats, channel-dense like the fused step; `stranded` counts
         # over the active rows (stranded rows are live, so they are all
         # in the active set whenever occ <= C)
-        st = state.stats
-        w_ej = won_ch & is_ej_ch
-        hops = (won_ch[:, None]
-                & (ch_type[:, None] == type_iota[None, :]))
-        if reap is None:
-            stranded = (valid & (out < 0)).sum().astype(jnp.int32)
-            reaped = st.reaped
-        else:
-            stranded = (undel & ~reap).sum().astype(jnp.int32)
-            reaped = st.reaped + reap.sum().astype(jnp.int32)
-        st = st.replace(
-            delivered=st.delivered + w_ej.sum(),
-            lat_sum=st.lat_sum + jnp.where(w_ej, t - witime, 0).sum(),
-            hops=st.hops + hops.astype(jnp.int32).sum(0),
-            stranded=stranded, reaped=reaped,
-            occ_peak=jnp.maximum(st.occ_peak, occ))
+        with phase("stats"):
+            st = state.stats
+            w_ej = won_ch & is_ej_ch
+            hops = (won_ch[:, None]
+                    & (ch_type[:, None] == type_iota[None, :]))
+            if reap is None:
+                stranded = (valid & (out < 0)).sum().astype(jnp.int32)
+                reaped = st.reaped
+            else:
+                stranded = (undel & ~reap).sum().astype(jnp.int32)
+                reaped = st.reaped + reap.sum().astype(jnp.int32)
+            st = st.replace(
+                delivered=st.delivered + w_ej.sum(),
+                lat_sum=st.lat_sum + jnp.where(w_ej, t - witime, 0).sum(),
+                hops=st.hops + hops.astype(jnp.int32).sum(0),
+                stranded=stranded, reaped=reaped,
+                occ_peak=jnp.maximum(st.occ_peak, occ))
         return state.replace(
             b_pkt=b_pkt, b_head=b_head, b_count=b_count,
             s_head=s_head, s_count=s_count, ch_busy=ch_busy,
@@ -561,137 +572,148 @@ def _make_unsharded(net, cfg, pattern, inject_mask, consts, route_kernel):
         t, key, rate_pkt, fl = t_key_rate_fl
         cached = not is_scheduled(fl)   # trace-time: see module docstring
         fl = resolve_epoch(fl, t)
-        state = inject(state, t, key, rate_pkt, fl)
-        occ = live_rows(state)
+        with phase("inject"):
+            state = inject(state, t, key, rate_pkt, fl)
+        with phase("stats"):
+            occ = live_rows(state)
 
         # request rows, in the oracle's order ([:ER]*NV buffer heads,
         # then T source queues) — `prio` IS the oracle's tie-break row id
-        bh = state.b_head[:ER]
-        head = state.b_pkt[(e_idx, v_idx, bh)].reshape(ER * NV, -1)
-        r_valid = ((state.b_count[:ER] > 0).reshape(-1)
-                   & (head[:, F_READY] <= t))
-        if cached:
-            out_b, cls_b, meta2_b = (head[:, F_OUT], head[:, F_CLS],
-                                     head[:, F_META2])
-        else:
-            out_b, cls_b, meta2_b = route_kernel(
-                fl, cur_rows, head[:, F_DEST], head[:, F_MIS],
-                head[:, F_META])
-        sq = state.s_pkt[(jnp.arange(T), state.s_head)]
-        out = jnp.concatenate([out_b, inject_ch]).astype(jnp.int32)
-        cls = jnp.concatenate([cls_b, zeros_t]).astype(jnp.int32)
-        itime = jnp.concatenate([head[:, F_ITIME], sq[:, F_ITIME]])
-        valid = jnp.concatenate([r_valid, state.s_count > 0])
-        rowok = valid & (out >= 0)
+        with phase("route"):
+            bh = state.b_head[:ER]
+            head = state.b_pkt[(e_idx, v_idx, bh)].reshape(ER * NV, -1)
+            r_valid = ((state.b_count[:ER] > 0).reshape(-1)
+                       & (head[:, F_READY] <= t))
+            if cached:
+                out_b, cls_b, meta2_b = (head[:, F_OUT], head[:, F_CLS],
+                                         head[:, F_META2])
+            else:
+                out_b, cls_b, meta2_b = route_kernel(
+                    fl, cur_rows, head[:, F_DEST], head[:, F_MIS],
+                    head[:, F_META])
+            sq = state.s_pkt[(jnp.arange(T), state.s_head)]
+            out = jnp.concatenate([out_b, inject_ch]).astype(jnp.int32)
+            cls = jnp.concatenate([cls_b, zeros_t]).astype(jnp.int32)
+            itime = jnp.concatenate([head[:, F_ITIME], sq[:, F_ITIME]])
+            valid = jnp.concatenate([r_valid, state.s_count > 0])
+            rowok = valid & (out >= 0)
         # router-death reaper: undeliverable rows (parked on -1 OR
         # requesting a dead channel) past the park age — disjoint from
         # winners, which need a live channel (see stats.reap_mask)
-        if reap_age:
-            undel = valid & ((out < 0)
-                             | ~fl["ch_alive"][jnp.clip(out, 0, E - 1)])
-            reap = undel & (t - itime >= reap_age)
-        else:
-            undel = reap = None
+        with phase("stats"):
+            if reap_age:
+                undel = valid & ((out < 0)
+                                 | ~fl["ch_alive"][jnp.clip(out, 0, E - 1)])
+                reap = undel & (t - itime >= reap_age)
+            else:
+                undel = reap = None
 
         # grant: per-row credit gather, one segment-min, dense channel
         # mask; at most one winner (row priority) per output channel
-        occ_min, occ_arg = _occ_tables(state.b_count, NC, vpc)
-        elig_ck = (occ_min < S) | is_ej_ch[:, None]
-        ok = rowok & _row_elig(elig_ck, out, cls, E)
-        ch_ok = (state.ch_busy == 0) & fl["ch_alive"]
-        if use_pallas:
-            won_ch, wprio, win_row = cycle_core(out, itime, ok, ch_ok,
-                                                r2=R2)
-        else:
-            won_ch, wprio = _grant(ok, out, itime, prio, ch_ok, E, R2,
-                                   use_combined)
-            win_row = None
+        with phase("route"):
+            occ_min, occ_arg = _occ_tables(state.b_count, NC, vpc)
+        with phase("grant"):
+            elig_ck = (occ_min < S) | is_ej_ch[:, None]
+            ok = rowok & _row_elig(elig_ck, out, cls, E)
+            ch_ok = (state.ch_busy == 0) & fl["ch_alive"]
+            if use_pallas:
+                won_ch, wprio, win_row = cycle_core(out, itime, ok, ch_ok,
+                                                    r2=R2)
+            else:
+                won_ch, wprio = _grant(ok, out, itime, prio, ch_ok, E, R2,
+                                       use_combined)
+                win_row = None
 
         # dense winner table: two E-row gathers (buffer / source rows)
-        is_buf = wprio < ER * NV
-        bclip = jnp.clip(wprio, 0, ER * NV - 1)
-        wb = head[bclip]
-        ws = sq[jnp.clip(wprio - ER * NV, 0, T - 1)]
-        wdest = jnp.where(is_buf, wb[:, F_DEST], ws[:, F_DEST])
-        witime = jnp.where(is_buf, wb[:, F_ITIME], ws[:, F_ITIME])
-        wmis = jnp.where(is_buf, wb[:, F_MIS], ws[:, F_MIS])
-        wmeta = jnp.where(
-            is_buf,
-            wb[:, F_META2] if cached else meta2_b[bclip],
-            0).astype(jnp.int32)
-        wcls = jnp.where(
-            is_buf,
-            wb[:, F_CLS] if cached else cls_b[bclip],
-            0).astype(jnp.int32)
-        wvc, wovc = _winner_vc(wcls, occ_min, occ_arg, NC, vpc)
-        entered = (wmis >= 0) & (ch_dst_wg == wmis)
-        wmis = jnp.where(entered, -1, wmis)
-        push = won_ch & ~is_ej_ch
-        vc_oh = wvc[:, None] == vc_iota[None, :]
-        whead = jnp.where(vc_oh, state.b_head, 0).sum(1)
-        wslot = (whead + wovc) % S
+        with phase("apply"):
+            is_buf = wprio < ER * NV
+            bclip = jnp.clip(wprio, 0, ER * NV - 1)
+            wb = head[bclip]
+            ws = sq[jnp.clip(wprio - ER * NV, 0, T - 1)]
+            wdest = jnp.where(is_buf, wb[:, F_DEST], ws[:, F_DEST])
+            witime = jnp.where(is_buf, wb[:, F_ITIME], ws[:, F_ITIME])
+            wmis = jnp.where(is_buf, wb[:, F_MIS], ws[:, F_MIS])
+            wmeta = jnp.where(
+                is_buf,
+                wb[:, F_META2] if cached else meta2_b[bclip],
+                0).astype(jnp.int32)
+            wcls = jnp.where(
+                is_buf,
+                wb[:, F_CLS] if cached else cls_b[bclip],
+                0).astype(jnp.int32)
+            wvc, wovc = _winner_vc(wcls, occ_min, occ_arg, NC, vpc)
+            entered = (wmis >= 0) & (ch_dst_wg == wmis)
+            wmis = jnp.where(entered, -1, wmis)
+            push = won_ch & ~is_ej_ch
+            vc_oh = wvc[:, None] == vc_iota[None, :]
+            whead = jnp.where(vc_oh, state.b_head, 0).sum(1)
+            wslot = (whead + wovc) % S
         if cached:
             # the route-once-per-hop evaluation: the pushed packet's
             # next-hop decision, dense over the E winner rows, with the
             # same (cleared-mis, meta-to-store) inputs the oracle feeds
             # its head-time call
-            out2, cls2, meta2 = route_kernel(fl, ch_dst, wdest, wmis,
-                                             wmeta)
-            tail = [out2.astype(jnp.int32), cls2.astype(jnp.int32),
-                    meta2.astype(jnp.int32)]
+            with phase("route"):
+                out2, cls2, meta2 = route_kernel(fl, ch_dst, wdest, wmis,
+                                                 wmeta)
+                tail = [out2.astype(jnp.int32), cls2.astype(jnp.int32),
+                        meta2.astype(jnp.int32)]
         else:
             z = jnp.zeros_like(wdest)
             tail = [z, z, z]
-        new_rec = jnp.stack(
-            [wdest, witime, wmis, wmeta, t + ch_lat] + tail, axis=-1)
-        pe = jnp.where(push, ch_iota, E)
-        b_pkt = state.b_pkt.at[(pe, wvc, wslot)].set(new_rec,
-                                                     mode="drop")
+        with phase("apply"):
+            new_rec = jnp.stack(
+                [wdest, witime, wmis, wmeta, t + ch_lat] + tail, axis=-1)
+            pe = jnp.where(push, ch_iota, E)
+            b_pkt = state.b_pkt.at[(pe, wvc, wslot)].set(new_rec,
+                                                         mode="drop")
 
-        # pops, recovered per row by comparing each row's output
-        # channel's winner id against its own row id — a vectorized
-        # gather + compare, no scatter (the Pallas core already emits
-        # this mask from the same comparison inside the kernel)
-        if win_row is None:
-            wprio_eff = jnp.where(won_ch, wprio, -1)
-            won_row = rowok & (wprio_eff[jnp.clip(out, 0, E - 1)]
-                               == row_id)
-        else:
-            won_row = win_row
-        # reaped rows pop like winners but push nowhere (disjoint masks:
-        # a winner's out channel is live, a reap victim's is -1 or
-        # dead); the source tail is reapable too, so pop_s widens
-        pop_row = won_row if reap is None else won_row | reap
-        pop1 = jnp.pad(
-            pop_row[: ER * NV].reshape(ER, NV).astype(jnp.int32),
-            ((0, E - ER), (0, 0)))
-        b_head = (state.b_head + pop1) % S
-        b_count = (state.b_count - pop1
-                   + (push[:, None] & vc_oh).astype(jnp.int32))
-        pop_s = pop_row[ER * NV:].astype(jnp.int32)
-        s_head = (state.s_head + pop_s) % Q
-        s_count = state.s_count - pop_s
-        ch_busy = jnp.where(won_ch, ch_ser - 1,
-                            jnp.maximum(state.ch_busy - 1, 0))
+            # pops, recovered per row by comparing each row's output
+            # channel's winner id against its own row id — a vectorized
+            # gather + compare, no scatter (the Pallas core already emits
+            # this mask from the same comparison inside the kernel)
+            if win_row is None:
+                wprio_eff = jnp.where(won_ch, wprio, -1)
+                won_row = rowok & (wprio_eff[jnp.clip(out, 0, E - 1)]
+                                   == row_id)
+            else:
+                won_row = win_row
+            # reaped rows pop like winners but push nowhere (disjoint
+            # masks: a winner's out channel is live, a reap victim's is
+            # -1 or dead); the source tail is reapable too, so pop_s
+            # widens
+            pop_row = won_row if reap is None else won_row | reap
+            pop1 = jnp.pad(
+                pop_row[: ER * NV].reshape(ER, NV).astype(jnp.int32),
+                ((0, E - ER), (0, 0)))
+            b_head = (state.b_head + pop1) % S
+            b_count = (state.b_count - pop1
+                       + (push[:, None] & vc_oh).astype(jnp.int32))
+            pop_s = pop_row[ER * NV:].astype(jnp.int32)
+            s_head = (state.s_head + pop_s) % Q
+            s_count = state.s_count - pop_s
+            ch_busy = jnp.where(won_ch, ch_ser - 1,
+                                jnp.maximum(state.ch_busy - 1, 0))
 
         # stats, channel-dense (bit-equal to the oracle's row sums: the
         # winners biject the granting channels and the sums are int32)
-        st = state.stats
-        w_ej = won_ch & is_ej_ch
-        hops = (won_ch[:, None]
-                & (ch_type[:, None] == type_iota[None, :]))
-        if reap is None:
-            stranded = (valid & (out < 0)).sum().astype(jnp.int32)
-            reaped = st.reaped
-        else:
-            stranded = (undel & ~reap).sum().astype(jnp.int32)
-            reaped = st.reaped + reap.sum().astype(jnp.int32)
-        st = st.replace(
-            delivered=st.delivered + w_ej.sum(),
-            lat_sum=st.lat_sum + jnp.where(w_ej, t - witime, 0).sum(),
-            hops=st.hops + hops.astype(jnp.int32).sum(0),
-            stranded=stranded, reaped=reaped,
-            occ_peak=jnp.maximum(st.occ_peak, occ))
+        with phase("stats"):
+            st = state.stats
+            w_ej = won_ch & is_ej_ch
+            hops = (won_ch[:, None]
+                    & (ch_type[:, None] == type_iota[None, :]))
+            if reap is None:
+                stranded = (valid & (out < 0)).sum().astype(jnp.int32)
+                reaped = st.reaped
+            else:
+                stranded = (undel & ~reap).sum().astype(jnp.int32)
+                reaped = st.reaped + reap.sum().astype(jnp.int32)
+            st = st.replace(
+                delivered=st.delivered + w_ej.sum(),
+                lat_sum=st.lat_sum + jnp.where(w_ej, t - witime, 0).sum(),
+                hops=st.hops + hops.astype(jnp.int32).sum(0),
+                stranded=stranded, reaped=reaped,
+                occ_peak=jnp.maximum(st.occ_peak, occ))
         return state.replace(
             b_pkt=b_pkt, b_head=b_head, b_count=b_count,
             s_head=s_head, s_count=s_count, ch_busy=ch_busy,
@@ -783,187 +805,199 @@ def _make_sharded(net, cfg, pattern, inject_mask, consts, route_kernel,
         fl = resolve_epoch(fl, t)
         sid = jax.lax.axis_index(axis).astype(jnp.int32)
         c0, t0 = sid * Ek, sid * Tk
-        state = inject(state, t, key, rate_pkt, fl, t0)
+        with phase("inject"):
+            state = inject(state, t, key, rate_pkt, fl, t0)
         # replicated counts (ghost rows stay zero), so every shard sees
         # the same global live-row census — no collective needed
-        occ = live_rows(state)
-        alive = jnp.pad(fl["ch_alive"], (0, ch_pad))
+        with phase("stats"):
+            occ = live_rows(state)
+        with phase("grant"):
+            alive = jnp.pad(fl["ch_alive"], (0, ch_pad))
 
         # local request rows over the shard's channel/terminal blocks;
         # priorities are GLOBAL ids, so tie-breaks match everywhere
-        cid = c0 + jnp.arange(Ek, dtype=jnp.int32)
-        bh_l = _sl(state.b_head, c0, Ek)
-        head = state.b_pkt[(e_loc, v_idx, bh_l)].reshape(Ek * NV, -1)
-        r_valid = ((_sl(state.b_count, c0, Ek) > 0).reshape(-1)
-                   & (head[:, F_READY] <= t))
-        if cached:
-            out_b, cls_b, meta2_b = (head[:, F_OUT], head[:, F_CLS],
-                                     head[:, F_META2])
-        else:
-            cur = ch_dst[(cid[:, None].repeat(NV, 1)).reshape(-1)]
-            out_b, cls_b, meta2_b = route_kernel(
-                fl, cur, head[:, F_DEST], head[:, F_MIS],
-                head[:, F_META])
-        sq = state.s_pkt[(jnp.arange(Tk), _sl(state.s_head, t0, Tk))]
-        out = jnp.concatenate(
-            [out_b, _sl(inject_ch, t0, Tk)]).astype(jnp.int32)
-        cls = jnp.concatenate([cls_b, zeros_tk]).astype(jnp.int32)
-        itime = jnp.concatenate([head[:, F_ITIME], sq[:, F_ITIME]])
-        valid = jnp.concatenate(
-            [r_valid, _sl(state.s_count, t0, Tk) > 0])
-        prio = jnp.concatenate(
-            [(cid[:, None] * NV + vc_iota[None, :]).reshape(-1),
-             Ep * NV + t0 + jnp.arange(Tk, dtype=jnp.int32)])
-        rowok = valid & (out >= 0)
+        with phase("route"):
+            cid = c0 + jnp.arange(Ek, dtype=jnp.int32)
+            bh_l = _sl(state.b_head, c0, Ek)
+            head = state.b_pkt[(e_loc, v_idx, bh_l)].reshape(Ek * NV, -1)
+            r_valid = ((_sl(state.b_count, c0, Ek) > 0).reshape(-1)
+                       & (head[:, F_READY] <= t))
+            if cached:
+                out_b, cls_b, meta2_b = (head[:, F_OUT], head[:, F_CLS],
+                                         head[:, F_META2])
+            else:
+                cur = ch_dst[(cid[:, None].repeat(NV, 1)).reshape(-1)]
+                out_b, cls_b, meta2_b = route_kernel(
+                    fl, cur, head[:, F_DEST], head[:, F_MIS],
+                    head[:, F_META])
+            sq = state.s_pkt[(jnp.arange(Tk), _sl(state.s_head, t0, Tk))]
+            out = jnp.concatenate(
+                [out_b, _sl(inject_ch, t0, Tk)]).astype(jnp.int32)
+            cls = jnp.concatenate([cls_b, zeros_tk]).astype(jnp.int32)
+            itime = jnp.concatenate([head[:, F_ITIME], sq[:, F_ITIME]])
+            valid = jnp.concatenate(
+                [r_valid, _sl(state.s_count, t0, Tk) > 0])
+            prio = jnp.concatenate(
+                [(cid[:, None] * NV + vc_iota[None, :]).reshape(-1),
+                 Ep * NV + t0 + jnp.arange(Tk, dtype=jnp.int32)])
+            rowok = valid & (out >= 0)
         # router-death reaper over the LOCAL rows (ghost rows are never
         # valid): undeliverable rows — parked on -1 OR requesting a
         # channel this epoch's fault set killed (dead eject at a dead
         # router; dead injection channel under a dead terminal's head)
-        if reap_age:
-            undel = valid & ((out < 0)
-                             | ~alive[jnp.clip(out, 0, Ep - 1)])
-            reap = undel & (t - itime >= reap_age)
-        else:
-            undel = reap = None
+        with phase("stats"):
+            if reap_age:
+                undel = valid & ((out < 0)
+                                 | ~alive[jnp.clip(out, 0, Ep - 1)])
+                reap = undel & (t - itime >= reap_age)
+            else:
+                undel = reap = None
 
         # grant: per-row credit gather (replicated tables), local
         # segment-min partials, then the [E'] pmin halo exchange
-        occ_min, occ_arg = _occ_tables(state.b_count, NC, vpc)
-        elig_ck = (occ_min < S) | is_ej_ch[:, None]
-        ok = rowok & _row_elig(elig_ck, out, cls, Ep)
-        ch_ok = (state.ch_busy == 0) & alive
-        seg = jnp.where(ok, out, Ep)
-        if use_combined:
-            key_g = jnp.where(ok, itime * R2 + prio, INF32)
-            m = jax.ops.segment_min(key_g, seg, num_segments=Ep + 1)
-            m = jax.lax.pmin(m[:Ep], axis)
-            m = jnp.where(ch_ok, m, INF32)
-            won_ch = m != INF32
-            wprio = jnp.where(won_ch, m & (R2 - 1), 0)
-        else:
-            m1 = jax.lax.pmin(jax.ops.segment_min(
-                jnp.where(ok, itime, INF32), seg,
-                num_segments=Ep + 1)[:Ep], axis)
-            # the age tie can span shards: re-mask the local rows
-            # against the GLOBAL per-channel age before the prio pass
-            tie = ok & (itime == m1[jnp.where(ok, out, 0)])
-            m2 = jax.lax.pmin(jax.ops.segment_min(
-                jnp.where(tie, prio, INF32), seg,
-                num_segments=Ep + 1)[:Ep], axis)
-            won_ch = ch_ok & (m1 != INF32)
-            wprio = jnp.where(won_ch, m2, 0)
+        with phase("route"):
+            occ_min, occ_arg = _occ_tables(state.b_count, NC, vpc)
+        with phase("grant"):
+            elig_ck = (occ_min < S) | is_ej_ch[:, None]
+            ok = rowok & _row_elig(elig_ck, out, cls, Ep)
+            ch_ok = (state.ch_busy == 0) & alive
+            seg = jnp.where(ok, out, Ep)
+            if use_combined:
+                key_g = jnp.where(ok, itime * R2 + prio, INF32)
+                m = jax.ops.segment_min(key_g, seg, num_segments=Ep + 1)
+                m = jax.lax.pmin(m[:Ep], axis)
+                m = jnp.where(ch_ok, m, INF32)
+                won_ch = m != INF32
+                wprio = jnp.where(won_ch, m & (R2 - 1), 0)
+            else:
+                m1 = jax.lax.pmin(jax.ops.segment_min(
+                    jnp.where(ok, itime, INF32), seg,
+                    num_segments=Ep + 1)[:Ep], axis)
+                # the age tie can span shards: re-mask the local rows
+                # against the GLOBAL per-channel age before the prio pass
+                tie = ok & (itime == m1[jnp.where(ok, out, 0)])
+                m2 = jax.lax.pmin(jax.ops.segment_min(
+                    jnp.where(tie, prio, INF32), seg,
+                    num_segments=Ep + 1)[:Ep], axis)
+                won_ch = ch_ok & (m1 != INF32)
+                wprio = jnp.where(won_ch, m2, 0)
 
         # winner-record halo exchange: the shard owning each winning row
         # gathers its record, psum merges (losers contribute zeros)
-        is_buf = wprio < Ep * NV
-        se = wprio // NV
-        sv = wprio % NV
-        ts = wprio - Ep * NV
-        lrow = jnp.where(is_buf, (se - c0) * NV + sv, ts - t0)
-        mine = won_ch & jnp.where(is_buf,
-                                  (se >= c0) & (se < c0 + Ek),
-                                  (ts >= t0) & (ts < t0 + Tk))
-        bclip = jnp.clip(lrow, 0, Ek * NV - 1)
-        wb = head[bclip]
-        ws = sq[jnp.clip(lrow, 0, Tk - 1)]
-        meta2b = (wb[:, F_META2] if cached
-                  else meta2_b[bclip].astype(jnp.int32))
-        clsb = (wb[:, F_CLS] if cached
-                else cls_b[bclip].astype(jnp.int32))
-        rec = jnp.where(
-            is_buf[:, None],
-            jnp.stack([wb[:, F_DEST], wb[:, F_ITIME], wb[:, F_MIS],
-                       meta2b, clsb], axis=-1),
-            jnp.stack([ws[:, F_DEST], ws[:, F_ITIME], ws[:, F_MIS],
-                       jnp.zeros_like(ts), jnp.zeros_like(ts)],
-                      axis=-1))
-        w = jax.lax.psum(jnp.where(mine[:, None], rec, 0), axis)
-        wdest, witime = w[:, W_DEST], w[:, W_ITIME]
-        wmis, wmeta, wcls = w[:, W_MIS], w[:, W_META], w[:, W_CLS]
-        wvc, wovc = _winner_vc(wcls, occ_min, occ_arg, NC, vpc)
-        entered = (wmis >= 0) & (ch_dst_wg == wmis)
-        wmis = jnp.where(entered, -1, wmis)
-        push = won_ch & ~is_ej_ch
-        vc_oh = wvc[:, None] == vc_iota[None, :]
-        whead = jnp.where(vc_oh, state.b_head, 0).sum(1)
-        wslot = (whead + wovc) % S
+        with phase("apply"):
+            is_buf = wprio < Ep * NV
+            se = wprio // NV
+            sv = wprio % NV
+            ts = wprio - Ep * NV
+            lrow = jnp.where(is_buf, (se - c0) * NV + sv, ts - t0)
+            mine = won_ch & jnp.where(is_buf,
+                                      (se >= c0) & (se < c0 + Ek),
+                                      (ts >= t0) & (ts < t0 + Tk))
+            bclip = jnp.clip(lrow, 0, Ek * NV - 1)
+            wb = head[bclip]
+            ws = sq[jnp.clip(lrow, 0, Tk - 1)]
+            meta2b = (wb[:, F_META2] if cached
+                      else meta2_b[bclip].astype(jnp.int32))
+            clsb = (wb[:, F_CLS] if cached
+                    else cls_b[bclip].astype(jnp.int32))
+            rec = jnp.where(
+                is_buf[:, None],
+                jnp.stack([wb[:, F_DEST], wb[:, F_ITIME], wb[:, F_MIS],
+                           meta2b, clsb], axis=-1),
+                jnp.stack([ws[:, F_DEST], ws[:, F_ITIME], ws[:, F_MIS],
+                           jnp.zeros_like(ts), jnp.zeros_like(ts)],
+                          axis=-1))
+            w = jax.lax.psum(jnp.where(mine[:, None], rec, 0), axis)
+            wdest, witime = w[:, W_DEST], w[:, W_ITIME]
+            wmis, wmeta, wcls = w[:, W_MIS], w[:, W_META], w[:, W_CLS]
+            wvc, wovc = _winner_vc(wcls, occ_min, occ_arg, NC, vpc)
+            entered = (wmis >= 0) & (ch_dst_wg == wmis)
+            wmis = jnp.where(entered, -1, wmis)
+            push = won_ch & ~is_ej_ch
+            vc_oh = wvc[:, None] == vc_iota[None, :]
+            whead = jnp.where(vc_oh, state.b_head, 0).sum(1)
+            wslot = (whead + wovc) % S
 
-        # replicated credit/head bookkeeping, reconstructed identically
-        # on every shard from the exchanged winner table
-        se_m = jnp.where(won_ch & is_buf, se, Ep)
-        pop1 = jnp.zeros((Ep, NV), jnp.int32).at[(se_m, sv)].add(
-            1, mode="drop")
-        if reap is not None:
-            # reap pops: only the owning shard sees a row's reap
-            # decision, but head/count state is replicated, so the reap
-            # pop table is exchanged like the winner records (shards
-            # own disjoint channel blocks, so psum is a concatenation)
-            pop1 = pop1 + jax.lax.psum(
-                jax.lax.dynamic_update_slice_in_dim(
-                    jnp.zeros((Ep, NV), jnp.int32),
-                    reap[:Ek * NV].reshape(Ek, NV).astype(jnp.int32),
-                    c0, axis=0), axis)
-        b_head = (state.b_head + pop1) % S
-        ts_m = jnp.where(won_ch & ~is_buf, ts, Tp)
-        pop_s = jnp.zeros((Tp,), jnp.int32).at[ts_m].add(1, mode="drop")
-        if reap is not None:
-            # source-queue reap pops: like the buffer reap pops above,
-            # the decision is shard-local but s_head/s_count are
-            # replicated, so the pop vector is psum-exchanged (shards
-            # own disjoint terminal blocks — psum is a concatenation)
-            pop_s = pop_s + jax.lax.psum(
-                jax.lax.dynamic_update_slice_in_dim(
-                    jnp.zeros((Tp,), jnp.int32),
-                    reap[Ek * NV:].astype(jnp.int32), t0, axis=0),
-                axis)
-        s_head = (state.s_head + pop_s) % Q
-        s_count = state.s_count - pop_s
-        b_count = (state.b_count - pop1
-                   + (push[:, None] & vc_oh).astype(jnp.int32))
-        ch_busy = jnp.where(won_ch, ch_ser - 1,
-                            jnp.maximum(state.ch_busy - 1, 0))
+            # replicated credit/head bookkeeping, reconstructed identically
+            # on every shard from the exchanged winner table
+            se_m = jnp.where(won_ch & is_buf, se, Ep)
+            pop1 = jnp.zeros((Ep, NV), jnp.int32).at[(se_m, sv)].add(
+                1, mode="drop")
+            if reap is not None:
+                # reap pops: only the owning shard sees a row's reap
+                # decision, but head/count state is replicated, so the reap
+                # pop table is exchanged like the winner records (shards
+                # own disjoint channel blocks, so psum is a concatenation)
+                pop1 = pop1 + jax.lax.psum(
+                    jax.lax.dynamic_update_slice_in_dim(
+                        jnp.zeros((Ep, NV), jnp.int32),
+                        reap[:Ek * NV].reshape(Ek, NV).astype(jnp.int32),
+                        c0, axis=0), axis)
+            b_head = (state.b_head + pop1) % S
+            ts_m = jnp.where(won_ch & ~is_buf, ts, Tp)
+            pop_s = jnp.zeros((Tp,), jnp.int32).at[ts_m].add(1, mode="drop")
+            if reap is not None:
+                # source-queue reap pops: like the buffer reap pops above,
+                # the decision is shard-local but s_head/s_count are
+                # replicated, so the pop vector is psum-exchanged (shards
+                # own disjoint terminal blocks — psum is a concatenation)
+                pop_s = pop_s + jax.lax.psum(
+                    jax.lax.dynamic_update_slice_in_dim(
+                        jnp.zeros((Tp,), jnp.int32),
+                        reap[Ek * NV:].astype(jnp.int32), t0, axis=0),
+                    axis)
+            s_head = (state.s_head + pop_s) % Q
+            s_count = state.s_count - pop_s
+            b_count = (state.b_count - pop1
+                       + (push[:, None] & vc_oh).astype(jnp.int32))
+            ch_busy = jnp.where(won_ch, ch_ser - 1,
+                                jnp.maximum(state.ch_busy - 1, 0))
 
         # local pushes: the shard's slice of the winner table, with the
         # route-once-per-hop evaluation on the local rows
-        push_l = _sl(push, c0, Ek)
-        wdest_l = _sl(wdest, c0, Ek)
-        wmis_l = _sl(wmis, c0, Ek)
-        wmeta_l = _sl(wmeta, c0, Ek)
-        base = [wdest_l, _sl(witime, c0, Ek), wmis_l, wmeta_l,
-                t + _sl(ch_lat, c0, Ek)]
+        with phase("apply"):
+            push_l = _sl(push, c0, Ek)
+            wdest_l = _sl(wdest, c0, Ek)
+            wmis_l = _sl(wmis, c0, Ek)
+            wmeta_l = _sl(wmeta, c0, Ek)
+            base = [wdest_l, _sl(witime, c0, Ek), wmis_l, wmeta_l,
+                    t + _sl(ch_lat, c0, Ek)]
         if cached:
-            out2, cls2, meta2 = route_kernel(
-                fl, _sl(ch_dst, c0, Ek), wdest_l, wmis_l, wmeta_l)
-            tail = [out2.astype(jnp.int32), cls2.astype(jnp.int32),
-                    meta2.astype(jnp.int32)]
+            with phase("route"):
+                out2, cls2, meta2 = route_kernel(
+                    fl, _sl(ch_dst, c0, Ek), wdest_l, wmis_l, wmeta_l)
+                tail = [out2.astype(jnp.int32), cls2.astype(jnp.int32),
+                        meta2.astype(jnp.int32)]
         else:
             z = jnp.zeros_like(wdest_l)
             tail = [z, z, z]
-        new_rec = jnp.stack(base + tail, axis=-1)
-        pe = jnp.where(push_l, jnp.arange(Ek, dtype=jnp.int32), Ek)
-        b_pkt = state.b_pkt.at[
-            (pe, _sl(wvc, c0, Ek), _sl(wslot, c0, Ek))].set(
-            new_rec, mode="drop")
+        with phase("apply"):
+            new_rec = jnp.stack(base + tail, axis=-1)
+            pe = jnp.where(push_l, jnp.arange(Ek, dtype=jnp.int32), Ek)
+            b_pkt = state.b_pkt.at[
+                (pe, _sl(wvc, c0, Ek), _sl(wslot, c0, Ek))].set(
+                new_rec, mode="drop")
 
-        st = state.stats
-        w_ej = won_ch & is_ej_ch
-        hops = (won_ch[:, None]
-                & (ch_type[:, None] == type_iota[None, :]))
-        if reap is None:
-            stranded = jax.lax.psum(
-                (valid & (out < 0)).sum().astype(jnp.int32), axis)
-            reaped = st.reaped
-        else:
-            stranded = jax.lax.psum(
-                (undel & ~reap).sum().astype(jnp.int32), axis)
-            reaped = st.reaped + jax.lax.psum(
-                reap.sum().astype(jnp.int32), axis)
-        st = st.replace(
-            delivered=st.delivered + w_ej.sum(),
-            lat_sum=st.lat_sum + jnp.where(w_ej, t - witime, 0).sum(),
-            hops=st.hops + hops.astype(jnp.int32).sum(0),
-            stranded=stranded, reaped=reaped,
-            occ_peak=jnp.maximum(st.occ_peak, occ))
+        with phase("stats"):
+            st = state.stats
+            w_ej = won_ch & is_ej_ch
+            hops = (won_ch[:, None]
+                    & (ch_type[:, None] == type_iota[None, :]))
+            if reap is None:
+                stranded = jax.lax.psum(
+                    (valid & (out < 0)).sum().astype(jnp.int32), axis)
+                reaped = st.reaped
+            else:
+                stranded = jax.lax.psum(
+                    (undel & ~reap).sum().astype(jnp.int32), axis)
+                reaped = st.reaped + jax.lax.psum(
+                    reap.sum().astype(jnp.int32), axis)
+            st = st.replace(
+                delivered=st.delivered + w_ej.sum(),
+                lat_sum=st.lat_sum + jnp.where(w_ej, t - witime, 0).sum(),
+                hops=st.hops + hops.astype(jnp.int32).sum(0),
+                stranded=stranded, reaped=reaped,
+                occ_peak=jnp.maximum(st.occ_peak, occ))
         return state.replace(
             b_pkt=b_pkt, b_head=b_head, b_count=b_count,
             s_head=s_head, s_count=s_count, ch_busy=ch_busy,

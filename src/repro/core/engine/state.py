@@ -23,6 +23,7 @@ from ... import env_int
 from ..topology import (NUM_CH_TYPES, FaultSchedule, FaultSet, Network,
                         glob_pair_alive, wg_channel_alive_frac)
 from ..routing import make_route_kernel, num_vcs, route_tables
+from ..spans import span
 
 INF32 = jnp.int32(2**31 - 1)
 
@@ -150,6 +151,7 @@ class SimState:
         return replace(self, **kw)
 
 
+@span("repro.build.lanes")
 def make_state(net: Network, cfg, NV: int,
                batch: tuple[int, ...] = (), *,
                ch_pad: int = 0, term_pad: int = 0) -> SimState:
@@ -227,6 +229,7 @@ def build_consts(net: Network, cfg):
 UGAL_WG_PENALTY_SCALE = 16
 
 
+@span("repro.build.lanes")
 def build_lane(net: Network, cfg,
                faults: FaultSet | FaultSchedule | None = None) -> dict:
     """Per-lane fault data (the `fl` pytree): alive masks + fault-dependent

@@ -18,6 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from ..spans import phase, span
 from ..topology import Network
 from ..traffic import as_pattern
 from .apply import make_apply_fn
@@ -37,6 +38,7 @@ from .stats import accumulate, reap_mask, track_occ, zero_stats
 STEP_IMPLS = ("jnp", "fused", "compact")
 
 
+@span("repro.build.step")
 def make_step(net: Network, cfg, pattern, inject_mask=None):
     """Returns (step, consts);
     step(state, (t, key, rate_pkt, fl)) -> (state, None).
@@ -72,15 +74,19 @@ def make_step(net: Network, cfg, pattern, inject_mask=None):
     def step(state, t_key_rate_fl):
         t, key, rate_pkt, fl = t_key_rate_fl
         fl = resolve_epoch(fl, t)
-        state = inject(state, t, key, rate_pkt, fl)
-        stats = track_occ(state.stats, state)
-        req, win, won_ch = arbitrate(state, t, fl)
+        with phase("inject"):
+            state = inject(state, t, key, rate_pkt, fl)
+        with phase("stats"):
+            stats = track_occ(state.stats, state)
+        req, win, won_ch = arbitrate(state, t, fl)   # route, grant
         alive = fl["ch_alive"]
-        reap = (reap_mask(req, t, reap_age, alive)
-                if reap_age else None)
-        stats = accumulate(stats, req, win, consts, t, reap=reap,
-                           ch_alive=alive if reap_age else None)
-        state = apply_moves(state, req, win, won_ch, t, reap=reap)
+        with phase("stats"):
+            reap = (reap_mask(req, t, reap_age, alive)
+                    if reap_age else None)
+            stats = accumulate(stats, req, win, consts, t, reap=reap,
+                               ch_alive=alive if reap_age else None)
+        with phase("apply"):
+            state = apply_moves(state, req, win, won_ch, t, reap=reap)
         return state.replace(stats=stats), None
 
     return step, consts
@@ -94,7 +100,9 @@ def run_scan(step, cycles, reset_at, state0, rate_pkt, key, fl):
         state, key = carry
         key, sub = jax.random.split(key)
         state, _ = step(state, (t, sub, rate_pkt, fl))
-        st = jax.lax.cond(t == reset_at, zero_stats, lambda s: s, state.stats)
+        with phase("stats"):
+            st = jax.lax.cond(t == reset_at, zero_stats, lambda s: s,
+                              state.stats)
         return (state.replace(stats=st), key), None
 
     (state, _), _ = jax.lax.scan(body, (state0, key), jnp.arange(cycles))

@@ -79,6 +79,7 @@ from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
 from ... import env_int
+from ..spans import phase, span
 from ..topology import (FaultSchedule, FaultSet, Network, as_fault_schedule,
                         compose_faults, final_faults)
 from ..traffic import as_pattern
@@ -112,6 +113,13 @@ def compile_counter() -> int:
 def clear_aot_cache() -> None:
     """Drop the compiled-executable cache (tests / memory)."""
     _AOT_CACHE.clear()
+
+
+def window_executables() -> list:
+    """The window executables (`start_lanes`) compiled or loaded in this
+    process, oldest first: what a profile of a session's windows ran,
+    whose HLO (`as_text()`) names each op's cycle phase."""
+    return [exe for key, exe in _AOT_CACHE.items() if key[0] == "window"]
 
 
 def host_devices() -> list:
@@ -235,8 +243,9 @@ def _scan_lanes(step, cycles, reset_at, per_lane_faults, K,
                 lambda s, k, r, f: step(s, (t, k, r, f)),
                 in_axes=(0, 0, 0, lane_axis))(state, subs_k[i], rate_pkt,
                                               lanes)
-            st = jax.lax.cond(t == reset_at, zero_stats, lambda s: s,
-                              state.stats)
+            with phase("stats"):
+                st = jax.lax.cond(t == reset_at, zero_stats, lambda s: s,
+                                  state.stats)
             state = state.replace(stats=st)
         return state, None
 
@@ -282,8 +291,9 @@ def _scan_lanes_seq(step, cycles, reset_at, per_lane_faults, K,
             for i in range(K):
                 t = ts_k[i]
                 state, _ = step(state, (t, subs_k[i], rate, fl))
-                st = jax.lax.cond(t == reset_at, zero_stats,
-                                  lambda s: s, state.stats)
+                with phase("stats"):
+                    st = jax.lax.cond(t == reset_at, zero_stats,
+                                      lambda s: s, state.stats)
                 state = state.replace(stats=st)
             return state, None
 
@@ -359,8 +369,9 @@ def _scan_window(step, window, reset_at, per_lane_faults, K,
                     lambda s, k, r, f: step(s, (t, k, r, f)),
                     in_axes=(0, 0, 0, lane_axis))(st, subs, rate_pkt,
                                                   lanes)
-                stats = jax.lax.cond(t == reset_at, zero_stats,
-                                     lambda s: s, st.stats)
+                with phase("stats"):
+                    stats = jax.lax.cond(t == reset_at, zero_stats,
+                                         lambda s: s, st.stats)
                 return st.replace(stats=stats)
 
             state = jax.lax.cond(t < t_end, advance, lambda st: st, state)
@@ -662,6 +673,7 @@ class LaneSession:
     def done(self) -> bool:
         return self.cycle >= self.total
 
+    @span("repro.advance")
     def advance(self) -> int:
         """Run one window (`window` cycles, clipped at the total budget);
         returns the new absolute cycle count."""
@@ -874,28 +886,29 @@ class BatchedSweep:
             state_spec = state_spec.replace(
                 b_pkt=PartitionSpec("lanes", "shards"),
                 s_pkt=PartitionSpec("lanes", "shards"))
-        if mesh is not None:
-            lane_sh = NamedSharding(mesh, PartitionSpec("lanes"))
-            repl_sh = NamedSharding(mesh, PartitionSpec())
-            if state_spec is None:
-                state0 = jax.device_put(state0, lane_sh)
-            else:
-                # PartitionSpec subclasses tuple, so the spec tree can't
-                # be tree-mapped over — build a NamedSharding-leaf tree
-                sh_tree = jax.tree.map(lambda _: lane_sh, state0)
-                sh_tree = sh_tree.replace(
-                    b_pkt=NamedSharding(
-                        mesh, PartitionSpec("lanes", "shards")),
-                    s_pkt=NamedSharding(
-                        mesh, PartitionSpec("lanes", "shards")))
-                state0 = jax.tree.map(jax.device_put, state0, sh_tree)
-            lane_rates = jax.device_put(lane_rates, lane_sh)
-            lane_keys = jax.device_put(lane_keys, lane_sh)
-            lane_data = jax.device_put(
-                lane_data, lane_sh if per_lane_faults else repl_sh)
-        elif device is not None:
-            state0, lane_rates, lane_keys, lane_data = jax.device_put(
-                (state0, lane_rates, lane_keys, lane_data), device)
+        with span("repro.build.lanes"):
+            if mesh is not None:
+                lane_sh = NamedSharding(mesh, PartitionSpec("lanes"))
+                repl_sh = NamedSharding(mesh, PartitionSpec())
+                if state_spec is None:
+                    state0 = jax.device_put(state0, lane_sh)
+                else:
+                    # PartitionSpec subclasses tuple, so the spec tree can't
+                    # be tree-mapped over — build a NamedSharding-leaf tree
+                    sh_tree = jax.tree.map(lambda _: lane_sh, state0)
+                    sh_tree = sh_tree.replace(
+                        b_pkt=NamedSharding(
+                            mesh, PartitionSpec("lanes", "shards")),
+                        s_pkt=NamedSharding(
+                            mesh, PartitionSpec("lanes", "shards")))
+                    state0 = jax.tree.map(jax.device_put, state0, sh_tree)
+                lane_rates = jax.device_put(lane_rates, lane_sh)
+                lane_keys = jax.device_put(lane_keys, lane_sh)
+                lane_data = jax.device_put(
+                    lane_data, lane_sh if per_lane_faults else repl_sh)
+            elif device is not None:
+                state0, lane_rates, lane_keys, lane_data = jax.device_put(
+                    (state0, lane_rates, lane_keys, lane_data), device)
         cache_key = (step, cycles, cfg.warmup, per_lane_faults, mesh,
                      device, kss, _sig((state0, lane_rates, lane_keys,
                                         lane_data)))
@@ -906,10 +919,11 @@ class BatchedSweep:
             fn = _make_dispatch_fn(step, cycles, cfg.warmup,
                                    per_lane_faults, mesh, state_spec, kss)
             before = _TRACE_COUNT[0]
-            t0 = time.perf_counter()
-            compiled = fn.lower(state0, lane_rates, lane_keys,
-                                lane_data).compile()
-            compile_s = time.perf_counter() - t0
+            with span("repro.lower") as lo:
+                lowered = fn.lower(state0, lane_rates, lane_keys, lane_data)
+            with span("repro.compile") as co:
+                compiled = lowered.compile()
+            compile_s = lo.seconds + co.seconds
             compiles = _TRACE_COUNT[0] - before
             _AOT_CACHE[cache_key] = compiled
         return _LanePlan(lane_triples, fsets,
@@ -918,6 +932,7 @@ class BatchedSweep:
                          pad_fraction, gform, cap,
                          getattr(step, "compact_rows", 0), kss, device)
 
+    @span("repro.build.lanes")
     def _prepare_lanes(self, lanes, force_stack: bool = False,
                        epochs: int | None = None):
         """Compose/sample per-lane fault data; returns the dense lane
@@ -1051,17 +1066,18 @@ class BatchedSweep:
                     f"restore cycle {cycle} outside [0, {cycles}]")
         t0 = jnp.asarray(cycle, jnp.int32)
         t_end = jnp.asarray(cycles, jnp.int32)
-        if mesh is not None:
-            lane_sh = NamedSharding(mesh, PartitionSpec("lanes"))
-            repl_sh = NamedSharding(mesh, PartitionSpec())
-            state0 = jax.device_put(state0, lane_sh)
-            lane_rates = jax.device_put(lane_rates, lane_sh)
-            lane_keys = jax.device_put(lane_keys, lane_sh)
-            lane_data = jax.device_put(
-                lane_data, lane_sh if per_lane_faults else repl_sh)
-        elif device is not None:
-            state0, lane_rates, lane_keys, lane_data = jax.device_put(
-                (state0, lane_rates, lane_keys, lane_data), device)
+        with span("repro.build.lanes"):
+            if mesh is not None:
+                lane_sh = NamedSharding(mesh, PartitionSpec("lanes"))
+                repl_sh = NamedSharding(mesh, PartitionSpec())
+                state0 = jax.device_put(state0, lane_sh)
+                lane_rates = jax.device_put(lane_rates, lane_sh)
+                lane_keys = jax.device_put(lane_keys, lane_sh)
+                lane_data = jax.device_put(
+                    lane_data, lane_sh if per_lane_faults else repl_sh)
+            elif device is not None:
+                state0, lane_rates, lane_keys, lane_data = jax.device_put(
+                    (state0, lane_rates, lane_keys, lane_data), device)
         cache_key = ("window", step, window, cfg.warmup,
                      per_lane_faults, mesh, device, kss,
                      _sig((state0, lane_keys, t0, t_end, lane_rates,
@@ -1073,10 +1089,12 @@ class BatchedSweep:
             fn = _make_window_fn(step, window, cfg.warmup,
                                  per_lane_faults, mesh, kss)
             before = _TRACE_COUNT[0]
-            t_c = time.perf_counter()
-            compiled = fn.lower(state0, lane_keys, t0, t_end, lane_rates,
-                                lane_data).compile()
-            compile_s = time.perf_counter() - t_c
+            with span("repro.lower") as lo:
+                lowered = fn.lower(state0, lane_keys, t0, t_end, lane_rates,
+                                   lane_data)
+            with span("repro.compile") as co:
+                compiled = lowered.compile()
+            compile_s = lo.seconds + co.seconds
             compiles = _TRACE_COUNT[0] - before
             _AOT_CACHE[cache_key] = compiled
         return LaneSession(self, lane_triples, fsets, window, cycles,

@@ -26,6 +26,8 @@ from dataclasses import dataclass, field
 
 import numpy as np
 
+from .spans import span
+
 MESH, LOCAL, GLOBAL, INJECT, EJECT = 0, 1, 2, 3, 4
 CH_TYPE_NAMES = ("mesh", "local", "global", "inject", "eject")
 NUM_CH_TYPES = 5
@@ -753,6 +755,7 @@ def _perimeter_walk(R: int) -> list[tuple[int, int]]:
     return walk
 
 
+@span("repro.build.topology")
 def build_switchless(p: SwitchlessParams, name: str = "switchless") -> Network:
     """Build the switch-less Dragonfly router/channel graph + routing tables."""
     R = p.R
@@ -1016,6 +1019,7 @@ class SwitchDragonflyParams:
         return self.t * self.switches_per_group * self.num_groups
 
 
+@span("repro.build.topology")
 def build_switch_dragonfly(p: SwitchDragonflyParams,
                            name: str = "dragonfly") -> Network:
     """Ideal-router switch-based Dragonfly (paper's baseline)."""
