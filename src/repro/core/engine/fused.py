@@ -1,9 +1,10 @@
-"""The fused cycle step (`SimConfig.step_impl="fused"`).
+"""The fused cycle step (`SimConfig.step_impl="fused"`, the default).
 
 `step_impl="jnp"` (`step.make_step`) is the classic phase pipeline and
-stays the oracle.  This module is the restructured hot path, bit-identical
-to the oracle by construction (integer ops only, same eligibility algebra,
-same tie-break order), built around two observations:
+stays the oracle, reached by name.  This module is the restructured hot
+path, bit-identical to the oracle by construction (integer ops only, same
+eligibility algebra, same tie-break order), built around two
+observations:
 
 ROUTE ONCE PER HOP, NOT ONCE PER CYCLE.  A packet's route out of a
 channel — output channel, requested VC class, next routing meta — is a

@@ -169,7 +169,7 @@ def make_state(net: Network, cfg, NV: int,
     E, T = net.num_channels + ch_pad, net.num_terminals + term_pad
     S, Q = cfg.buf_pkts, cfg.srcq_pkts
     nf = (NUM_FUSED_FIELDS
-          if getattr(cfg, "step_impl", "jnp") in CACHED_ROUTE_IMPLS
+          if cfg.step_impl in CACHED_ROUTE_IMPLS
           else NUM_FIELDS)
     z = lambda *s: jnp.zeros(batch + s, dtype=jnp.int32)
     return SimState(

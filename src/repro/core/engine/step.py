@@ -29,12 +29,13 @@ from .stats import accumulate, reap_mask, track_occ, zero_stats
 
 # the valid `cfg.step_impl` values — the single source of truth
 # (SimConfig and exp.RoutingSpec validate against this): "jnp" is the
-# phase pipeline below (the oracle), "fused" the per-channel-winner
-# restructuring in `fused.py` (bit-identical; the paper-scale fast
-# path), "compact" the occupancy-compacted fused step (also fused.py:
-# live rows compacted into a capacity-C active set before arbitration,
-# bit-identical with a post-run capacity certificate — see
-# `fused.make_compact_step` and the sweep's escalation ladder)
+# phase pipeline below (the oracle, reached by name), "fused" the
+# per-channel-winner restructuring in `fused.py` (bit-identical; the
+# default `SimConfig.step_impl`), "compact" the occupancy-compacted
+# fused step (also fused.py: live rows compacted into a capacity-C
+# active set before arbitration, bit-identical with a post-run capacity
+# certificate — see `fused.make_compact_step` and the sweep's
+# escalation ladder)
 STEP_IMPLS = ("jnp", "fused", "compact")
 
 
@@ -53,7 +54,7 @@ def make_step(net: Network, cfg, pattern, inject_mask=None):
     tables — mid-run link death is the epoch index advancing, and every
     in-flight packet is re-routed on the surviving subgraph from the next
     cycle on (buffered packets are preserved, never dropped)."""
-    impl = getattr(cfg, "step_impl", "jnp")
+    impl = cfg.step_impl
     if impl == "fused":
         from .fused import make_fused_step
         return make_fused_step(net, cfg, pattern, inject_mask)

@@ -85,7 +85,8 @@ from ..topology import (FaultSchedule, FaultSet, Network, as_fault_schedule,
 from ..traffic import as_pattern
 from .fused import (fused_pad, grant_form, make_compact_step,
                     make_fused_step, next_rung)
-from .state import build_lane, make_state, stack_lanes
+from .state import (CACHED_ROUTE_IMPLS, build_lane, make_state,
+                    stack_lanes)
 from .stats import finalize, zero_stats
 from .step import make_step
 
@@ -183,6 +184,15 @@ def lane_mesh(shards: int = 1) -> Mesh | None:
         return Mesh(np.array(devs).reshape(nd // shards, shards),
                     ("lanes", "shards"))
     return Mesh(np.array(devs), ("lanes",))
+
+
+def _route_cached(cfg, fault_sets) -> bool:
+    """Whether the compiled step reads each head's route from the cached
+    record tail: the fused and compact steps do, except on a dispatch
+    with warm-fault (epoch-scheduled) lanes, which route every cycle
+    (see `engine.fused`)."""
+    return (cfg.step_impl in CACHED_ROUTE_IMPLS
+            and not any(isinstance(f, FaultSchedule) for f in fault_sets))
 
 
 def _key_chain(key, cycles: int):
@@ -429,6 +439,8 @@ class LaneRun(NamedTuple):
     compile_s: float       # trace + compile wall time (0.0 on cache hit)
     compile_count: int     # jit compilations this dispatch triggered
     fault_sets: list       # composed per-lane fault states (None=pristine)
+    step_impl: str         # the cycle-step form the dispatch compiled
+    route_cached: bool     # heads read the route-once cache
     placement: str = "single"   # "single" | "lanes:L" | "lanes:L,shards:K"
     pad_fraction: float = 0.0   # ghost share of the dispatched state
     grant_form: str = "two_pass"   # "combined" | "two_pass" (see fused.py)
@@ -458,6 +470,11 @@ class SweepResult:
     rates: list[float]
     seeds: list[int]
     results: list[list]        # [num_rates][num_seeds] of SimResult
+    # the cycle-step form the dispatch compiled, and whether its heads
+    # read routes from the route-once cache (false for the oracle and
+    # for warm-fault lanes, which route every cycle)
+    step_impl: str
+    route_cached: bool
     compile_count: int = 0     # jit compilations this sweep triggered
     wall_s: float = 0.0
     compile_s: float = 0.0
@@ -615,9 +632,10 @@ class _PendingLanes:
                             self._sweep._chips(self._fsets[i]))
                    for i in range(self._B)]     # ghost pad lanes excluded
         return LaneRun(results, wall, self._compile_s, self._compiles,
-                       self._fsets, self._placement, self._pad_frac,
-                       self._grant_form, occ, self._capacity,
-                       self._superstep)
+                       self._fsets, cfg.step_impl,
+                       _route_cached(cfg, self._fsets), self._placement,
+                       self._pad_frac, self._grant_form, occ,
+                       self._capacity, self._superstep)
 
 
 class LaneSession:
@@ -669,6 +687,17 @@ class LaneSession:
         self.capacity = capacity      # compact rung (0 for dense steps)
         self.superstep = superstep
         self.num_lanes = len(lane_triples)
+
+    @property
+    def step_impl(self) -> str:
+        """The cycle-step form the session compiled (`grant_form` is its
+        arbitration form)."""
+        return self.sweep.cfg.step_impl
+
+    @property
+    def route_cached(self) -> bool:
+        """Whether the session's heads read the route-once cache."""
+        return _route_cached(self.sweep.cfg, self.fault_sets)
 
     def done(self) -> bool:
         return self.cycle >= self.total
@@ -732,9 +761,9 @@ class LaneSession:
                             self.sweep._chips(self.fault_sets[i]))
                    for i in range(self.num_lanes)]
         return LaneRun(results, 0.0, self.compile_s, self.compile_count,
-                       self.fault_sets, self.placement, self.pad_fraction,
-                       self.grant_form, occ, self.capacity,
-                       self.superstep)
+                       self.fault_sets, self.step_impl, self.route_cached,
+                       self.placement, self.pad_fraction, self.grant_form,
+                       occ, self.capacity, self.superstep)
 
 
 class BatchedSweep:
@@ -821,7 +850,7 @@ class BatchedSweep:
         cfg = self.cfg
         B = int(lane_rates.shape[0])
         cycles = cfg.warmup + cfg.measure
-        impl = getattr(cfg, "step_impl", "jnp")
+        impl = cfg.step_impl
         fused = impl == "fused"
         compact = impl == "compact"
         K = channel_shards() if (fused and device is None) else 1
@@ -1026,7 +1055,7 @@ class BatchedSweep:
         Bp = target + (-target) % nd
         pad = Bp - B
         placement = "single" if mesh is None else f"lanes:{nd}"
-        impl = getattr(cfg, "step_impl", "jnp")
+        impl = cfg.step_impl
         gform = (grant_form(self.net, cfg, 1) if impl in ("fused", "compact")
                  else "two_pass")
         step = self.step
@@ -1181,7 +1210,9 @@ class BatchedSweep:
                            compact_capacity=run.compact_capacity,
                            superstep=run.superstep,
                            escalations=run.escalations,
-                           escalation_compiles=run.escalation_compiles)
+                           escalation_compiles=run.escalation_compiles,
+                           step_impl=run.step_impl,
+                           route_cached=run.route_cached)
 
     def run_faults(self, offered_per_chip: float, fault_grid,
                    seeds=None) -> SweepResult:
@@ -1227,4 +1258,6 @@ class BatchedSweep:
                            compact_capacity=run.compact_capacity,
                            superstep=run.superstep,
                            escalations=run.escalations,
-                           escalation_compiles=run.escalation_compiles)
+                           escalation_compiles=run.escalation_compiles,
+                           step_impl=run.step_impl,
+                           route_cached=run.route_cached)

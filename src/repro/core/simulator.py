@@ -67,14 +67,15 @@ class SimConfig:
     # path, default and oracle) or "pallas" (the fused netsim kernel,
     # `repro.kernels.netsim` — bit-identical, TPU-ready fast path)
     grant_impl: str = "jnp"
-    # cycle-step implementation: "jnp" (the modular phase pipeline,
-    # default and oracle), "fused" (the per-channel-winner fused step,
-    # `engine.fused` — bit-identical, and the only step the 2-D
-    # (lanes x shards) channel-sharded mesh can run), or "compact"
-    # (the fused step with live rows compacted into a capacity-C active
-    # set before arbitration — bit-identical, occupancy-proportional;
-    # see `engine.fused.make_compact_step` and REPRO_COMPACT_CAP)
-    step_impl: str = "jnp"
+    # cycle-step implementation: "fused" (the default: the
+    # per-channel-winner step with the route-once-per-hop cache,
+    # `engine.fused` — bit-identical to the oracle, and the only step the
+    # 2-D (lanes x shards) channel-sharded mesh can run), "jnp" (the
+    # modular phase pipeline, the oracle, by name), or "compact" (the
+    # fused step with live rows compacted into a capacity-C active set
+    # before arbitration — bit-identical, occupancy-proportional; see
+    # `engine.fused.make_compact_step` and REPRO_COMPACT_CAP)
+    step_impl: str = "fused"
     # router-death reaper park age (cycles): packets parked on the -1
     # non-channel (destination dead / unroutable) are dropped once their
     # generation age reaches this, tallied in `SimStats.reaped` /

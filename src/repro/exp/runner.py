@@ -112,6 +112,9 @@ class GridResult:
     fault_labels: list          # [F]
     fault_fracs: list           # [F] mean failed-link fraction over seeds
     results: list               # [F][R][S] of SimResult
+    step_impl: str              # cycle-step form the grid compiled
+    route_cached: bool          # heads read the route-once cache (false
+                                # for the oracle and warm-fault lanes)
     compile_count: int = 0
     wall_s: float = 0.0         # execution wall (compile excluded); for
                                 # round-robined cells this spans dispatch
@@ -141,6 +144,8 @@ class GridResult:
         """One fault row as a legacy `SweepResult` (rate x seed grid)."""
         return SweepResult(rates=list(self.rates), seeds=list(self.seeds),
                            results=self.results[fault_idx],
+                           step_impl=self.step_impl,
+                           route_cached=self.route_cached,
                            compile_count=self.compile_count,
                            wall_s=self.wall_s, placement=self.placement,
                            pad_fraction=self.pad_fraction,
@@ -313,6 +318,7 @@ def run_experiment(spec: ExperimentSpec, verbose: bool = False
             traffic=cell.traffic, rates=rates, seeds=seeds,
             fault_labels=[f.label for f in axes.faults],
             fault_fracs=fracs, results=results,
+            step_impl=run.step_impl, route_cached=run.route_cached,
             compile_count=compiles, wall_s=run.wall_s,
             compile_s=compile_s,
             placement=getattr(run, "placement", "single"),

@@ -269,10 +269,10 @@ class RoutingSpec:
     # arbitration grant implementation: "jnp" (segment_min path, the
     # default and oracle) | "pallas" (fused repro.kernels.netsim kernel)
     grant_impl: str = "jnp"
-    # cycle-step implementation: "jnp" (phase-pipeline oracle) | "fused"
-    # (route-once-per-hop fused step, the perf path; supports channel
-    # sharding via REPRO_CHANNEL_SHARDS)
-    step_impl: str = "jnp"
+    # cycle-step implementation: "fused" (route-once-per-hop fused step,
+    # the default; supports channel sharding via REPRO_CHANNEL_SHARDS) |
+    # "jnp" (phase-pipeline oracle) | "compact" (occupancy-compacted)
+    step_impl: str = "fused"
     # router-death reaper policy (park-forever off by default)
     reaper: ReaperSpec = ReaperSpec()
 

@@ -63,7 +63,7 @@ def test_route_fn_batch_pure(cgroup_net):
 
 
 def test_step_grants_at_most_one_winner_per_channel(cgroup_net):
-    cfg = SimConfig(warmup=10, measure=10, vcs_per_class=2)
+    cfg = SimConfig(warmup=10, measure=10, vcs_per_class=2, step_impl="jnp")
     consts, route_kernel = engine.build_consts(cgroup_net, cfg)
     inject = engine.make_inject_fn(cgroup_net, cfg, consts, TR.uniform(cgroup_net))
     arbitrate = engine.make_arbitrate_fn(cgroup_net, cfg, consts,

@@ -198,7 +198,7 @@ def test_engine_never_grants_dead_channel(small_net):
     net = small_net
     faults = _fault_for(net, "updown", 23)
     cfg = SimConfig(warmup=10, measure=10, vc_mode="updown",
-                    vcs_per_class=2)
+                    vcs_per_class=2, step_impl="jnp")
     consts, route_kernel = engine.build_consts(net, cfg)
     inject = engine.make_inject_fn(net, cfg, consts, TR.uniform(net))
     arbitrate = engine.make_arbitrate_fn(net, cfg, consts, route_kernel)

@@ -145,3 +145,118 @@ def test_step_impl_spec_roundtrip():
                     "params": dict(a=1, b=1, m=2, n=6, noc=2, g=1)},
         traffics={"pattern": "uniform"}, routings=r, axes=axes)
     assert ExperimentSpec.from_dict(spec.to_dict()) == spec
+
+
+# ---------------------------------------------------------------------------
+# the fused step is the default step form
+# ---------------------------------------------------------------------------
+
+SESSION_LANES = [(0.6, 1, None), (1.2, 2, None)]
+
+
+def _windowed(cfg, lanes, window=10, windows=2):
+    """A `start_lanes` session filled to `cfg.warmup`, then advanced
+    `windows` more windows: (session, host state)."""
+    from repro.core.engine import BatchedSweep
+    sess = BatchedSweep(NET, cfg, TR.uniform(NET)).start_lanes(
+        lanes, window=window)
+    while sess.cycle < cfg.warmup:
+        sess.advance()
+    for _ in range(windows):
+        sess.advance()
+    return sess, jax.tree.map(np.asarray, sess.state)
+
+
+def test_default_step_is_fused_with_cached_route_records():
+    from repro.core.engine import make_state, make_step
+    from repro.core.engine.state import NUM_FUSED_FIELDS
+    from repro.exp.spec import RoutingSpec, SweepAxes
+
+    axes = SweepAxes(rates=(0.5,), warmup=10, measure=20)
+    for cfg in (SimConfig(), RoutingSpec().to_simconfig(axes)):
+        assert cfg.step_impl == "fused"
+        step, consts = make_step(NET, cfg, TR.uniform(NET))
+        assert step.__module__ == "repro.core.engine.fused"
+        state = make_state(NET, cfg, consts["NV"])
+        assert state.b_pkt.shape[-1] == NUM_FUSED_FIELDS
+
+
+def test_default_session_matches_oracle_session():
+    """A default-config windowed session (fill, then two windows, two
+    lanes) equals the same session on the named oracle in every counter
+    and in the in-flight state."""
+    cfg = SimConfig(warmup=30, measure=200)
+    fused_sess, got = _windowed(cfg, SESSION_LANES)
+    oracle_sess, want = _windowed(
+        SimConfig(warmup=30, measure=200, step_impl="jnp"), SESSION_LANES)
+    assert (fused_sess.step_impl, oracle_sess.step_impl) == ("fused", "jnp")
+    assert fused_sess.cycle == oracle_sess.cycle == 50
+    for name in got.stats.__dataclass_fields__:
+        np.testing.assert_array_equal(getattr(got.stats, name),
+                                      getattr(want.stats, name), name)
+    assert int(got.stats.delivered.sum()) > 0
+    for name in ("b_count", "s_count", "ch_busy"):
+        np.testing.assert_array_equal(getattr(got, name),
+                                      getattr(want, name), name)
+
+
+def _combined_limit(cfg):
+    """The largest cycle budget whose packed grant key fits int32 on
+    NET: ``cycles * R2 + R2 - 1 < 2**31 - 1``."""
+    from repro.core.engine.fused import compact_rows
+    r2 = 1 << (compact_rows(NET, cfg) - 1).bit_length()
+    return 2**31 // r2 - 2
+
+
+@pytest.mark.parametrize("over", [0, 1])
+def test_session_reports_grant_form_at_the_overflow_boundary(over):
+    from repro.core.engine import BatchedSweep
+    limit = _combined_limit(SimConfig())
+    cfg = SimConfig(warmup=10, measure=limit - 10 + over)
+    sess = BatchedSweep(NET, cfg, TR.uniform(NET)).start_lanes(
+        SESSION_LANES, window=10)
+    assert sess.step_impl == "fused"
+    assert sess.route_cached
+    assert sess.grant_form == ("two_pass" if over else "combined")
+
+
+@pytest.mark.parametrize("impl", ["fused", "compact", "jnp"])
+def test_session_and_sweep_report_route_cache(impl):
+    """`route_cached` is false on the oracle and on a warm-schedule lane,
+    which routes every cycle; `SweepResult` reports the same."""
+    from repro.core.engine import BatchedSweep
+    cfg = SimConfig(warmup=10, measure=20, step_impl=impl)
+    sweep = BatchedSweep(NET, cfg, TR.uniform(NET))
+    cold = sweep.start_lanes(SESSION_LANES, window=10)
+    warm = sweep.start_lanes([(0.6, 1, _schedule("baseline"))], window=10)
+    assert (cold.step_impl, warm.step_impl) == (impl, impl)
+    assert cold.route_cached == (impl != "jnp")
+    assert not warm.route_cached
+    grid = sweep.run([0.6], seeds=(1,))
+    assert (grid.step_impl, grid.route_cached) == (impl, impl != "jnp")
+    assert grid.grant_form == cold.grant_form
+
+
+@pytest.mark.parametrize("impl", ["fused", "jnp"])
+@pytest.mark.parametrize("warm", [False, True])
+def test_experiment_grid_reports_step_form(impl, warm):
+    """`GridResult` and the `SweepResult` it hands out carry the step form
+    the grid ran and whether its route cache engaged."""
+    from repro.exp import run_experiment
+    from repro.exp.spec import (ExperimentSpec, FaultSpec, RoutingSpec,
+                                SweepAxes)
+    faults = (FaultSpec(kind="routers", num=1, onsets=(15,)) if warm
+              else FaultSpec())
+    spec = ExperimentSpec(
+        name="step-form",
+        topologies={"kind": "switchless",
+                    "params": dict(a=1, b=1, m=2, n=6, noc=2, g=3)},
+        traffics={"pattern": "uniform"},
+        routings=RoutingSpec(vc_mode="updown", step_impl=impl),
+        axes=SweepAxes(rates=(0.6,), faults=(faults,), warmup=10,
+                       measure=20))
+    grid = run_experiment(spec).grids[0]
+    cached = impl != "jnp" and not warm
+    assert (grid.step_impl, grid.route_cached) == (impl, cached)
+    res = grid.sweep_result()
+    assert (res.step_impl, res.route_cached) == (impl, cached)

@@ -63,7 +63,7 @@ def _drive(net, cfg, fl, cycles=8, rate=0.6):
 @pytest.mark.parametrize("faulted", [False, True])
 def test_grant_parity_engine_states(net, vc_mode, faulted):
     """kernel == oracle == engine path, bit for bit, on live states."""
-    cfg = SimConfig(vc_mode=vc_mode, vcs_per_class=2)
+    cfg = SimConfig(vc_mode=vc_mode, vcs_per_class=2, step_impl="jnp")
     faults = _faults_for(net, vc_mode) if faulted else None
     fl = build_lane(net, cfg, faults)
     consts, pairs = _drive(net, cfg, fl)
@@ -91,7 +91,7 @@ def test_grant_pallas_end_to_end_sweep():
     results = {}
     for impl in ("jnp", "pallas"):
         cfg = SimConfig(warmup=31, measure=127, vcs_per_class=2,
-                        grant_impl=impl)
+                        grant_impl=impl, step_impl="jnp")
         sim = Simulator(net, cfg, TR.uniform(net))
         grid = sim.sweep_grid([0.5, 1.2], seeds=(0,))
         results[impl] = [

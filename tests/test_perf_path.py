@@ -24,7 +24,7 @@ def test_stranded_gauge_counts_minus_one_requests(updown_net):
     """A head-of-line request parked on the -1 non-channel shows up in
     `SimStats.stranded` (and only there — it is never granted)."""
     net = updown_net
-    cfg = SimConfig(vc_mode="updown", vcs_per_class=1)
+    cfg = SimConfig(vc_mode="updown", vcs_per_class=1, step_impl="jnp")
     consts, route_kernel = engine.build_consts(net, cfg)
     fl = build_lane(net, cfg)
     state = make_state(net, cfg, consts["NV"])
@@ -96,7 +96,7 @@ def test_expand_vcs_single_gather_matches_loop(updown_net):
     equals the old per-VC loop (argmin ties break toward the lowest VC)."""
     from repro.core.engine.arbitrate import expand_vcs
     net = updown_net
-    cfg = SimConfig(vc_mode="updown", vcs_per_class=3)
+    cfg = SimConfig(vc_mode="updown", vcs_per_class=3, step_impl="jnp")
     consts, route_kernel = engine.build_consts(net, cfg)
     fl = build_lane(net, cfg)
     inject = engine.make_inject_fn(net, cfg, consts, TR.uniform(net))

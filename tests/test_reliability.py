@@ -203,7 +203,8 @@ def test_conservation_across_repair_boundary_strands_then_revives(small_net):
     rf = _router_faults(net, 2, 37)
     sch = T.FaultSchedule(((0, T.FaultSet()), (40, rf),
                            (160, T.FaultSet())))
-    cfg = SimConfig(warmup=0, measure=1, vc_mode="updown", vcs_per_class=2)
+    cfg = SimConfig(warmup=0, measure=1, vc_mode="updown", vcs_per_class=2,
+                    step_impl="jnp")
     trace = conservation_trace(net, cfg, faults=sch, cycles=700,
                                rate=0.05, stop_inject_at=90)
     assert max(r["stranded"] for r in trace) > 0, "router death must strand"

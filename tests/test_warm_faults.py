@@ -252,7 +252,7 @@ def test_stranded_packet_request_never_granted(small_net):
     """A request for the -1 non-channel (warm-stranded packet) must never
     win arbitration or corrupt the trailing eject channel's accounting."""
     net = small_net
-    cfg = SimConfig(vc_mode="updown", vcs_per_class=1)
+    cfg = SimConfig(vc_mode="updown", vcs_per_class=1, step_impl="jnp")
     consts, route_kernel = engine.build_consts(net, cfg)
     fl = build_lane(net, cfg)
     state = make_state(net, cfg, consts["NV"])
