@@ -7,9 +7,10 @@
 In one process that holds the chip: for each seed, the cell's session is
 opened, filled and advanced `--windows` windows through the timed path,
 and the three compared numbers of `bench/check.py` are read against the
-plain reference.  For the first `--control` seeds the control (the
-reference with its statistics one precision lower) is read as well.  The
-benchmark's own runs never run the control.
+plain reference that covers the cell (`bench/references/`).  For the
+first `--control` seeds the control (that reference with its statistics
+one precision lower) is read as well.  The benchmark's own runs never run
+the control.
 """
 import argparse
 import json
@@ -50,7 +51,7 @@ def main(argv=None) -> int:
         got = check.program_records(sess.state, sess.num_lanes)
         cycles = sess.cycle
         sess = None
-        lanes = cell.lane_seeds(seed)
+        lanes = cell.lanes(seed)
         want = check.reference_records(cell.config, cell.traffic, lanes,
                                        cycles)
         row = {"seed": seed, "cycles": cycles,

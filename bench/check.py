@@ -1,9 +1,10 @@
 """The comparison that decides a run's `correct`.
 
-After the timed windows the program's lanes stand at some cycle.  The plain
-reference (`bench.reference`) replays every lane from an empty fabric to
-that cycle on the host, from the same seeds, and each lane's statistics and
-in-flight state are compared with what the timed path left on the device:
+After the timed windows the program's lanes stand at some cycle.  A plain
+reference replays every lane from an empty fabric to that cycle on the
+host, from the same seeds and offered rates, and each lane's statistics
+and in-flight state are compared with what the timed path left on the
+device:
 
     counters_off  integer counters that differ (generated, delivered,
                   dropped, stranded, reaped, the live-row high-water mark,
@@ -16,18 +17,35 @@ in-flight state are compared with what the timed path left on the device:
 The simulation is integer and the latency sum adds whole cycles in float32,
 so every number is exact and every limit is 0.
 
+The plain references are the modules of `bench/references/` (a file whose
+name starts with `_` is a helper, not a reference).  Each declares the
+cells it implements in `IMPLEMENTS`, a dict from a dotted path into
+{"config": <configuration file>, "traffic": <traffic file>} to the values
+it replays there, and replays lanes through
+`replay(config, traffic, lanes, cycles, stats_dtype)`: `lanes` are
+(offered, seed) pairs, and it returns one record per lane with the keys
+`compare` reads.  A cell is replayed by the one module whose declaration
+covers it; none, or more than one, refuses the cell.  So new semantics
+bring a new module and no edit here.
+
 The control (`control=True`) is the reference with its statistics one
 precision lower than the configuration states: int16 counters and a
 bfloat16 latency sum.
 """
 from __future__ import annotations
 
+import functools
+import glob
+import importlib.util
+import os
+
 import numpy as np
 
-from . import reference as ref
-
+REFERENCES = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                          "references")
 LIMITS = {"counters_off": 0, "lat_sum_gap": 0.0, "inflight_off": 0}
-INTEGER = ref.COUNTERS
+INTEGER = ("generated", "delivered", "dropped", "stranded", "reaped",
+           "occ_peak")
 FULL = (np.int32, np.float32)
 
 
@@ -53,57 +71,78 @@ def program_records(state, lanes: int) -> list:
     return out
 
 
-# what the plain reference implements; anything else it would replay with
-# the wrong semantics
-SUPPORTED = {"topology kind": ("switchless",), "traffic pattern": ("uniform",),
-             "route_mode": ("min",), "vc_mode": ("baseline",)}
+def references(where: str | None = None) -> dict:
+    """{name: module} of the plain references in the directory `where`
+    (default `REFERENCES`)."""
+    return _load(where or REFERENCES)
+
+
+@functools.cache
+def _load(where: str) -> dict:
+    """Each module of `where` whose file name does not start with `_`."""
+    out = {}
+    for path in sorted(glob.glob(os.path.join(where, "*.py"))):
+        name = os.path.basename(path)[:-3]
+        if name.startswith("_"):
+            continue
+        spec = importlib.util.spec_from_file_location(
+            f"bench_reference_{name}", path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        out[name] = mod
+    return out
+
+
+def _field(cell: dict, path: str):
+    for key in path.split("."):
+        if not isinstance(cell, dict) or key not in cell:
+            return None
+        cell = cell[key]
+    return cell
+
+
+def _has(mod) -> str:
+    return ", ".join(f"{k} {'/'.join(map(str, v))}"
+                     for k, v in mod.IMPLEMENTS.items())
+
+
+def find_reference(config: dict, traffic: dict, where: str | None = None):
+    """The one module of `where` (default `REFERENCES`) whose declaration
+    covers the cell.  Refuses a cell that none covers, naming what each
+    lacks and has, and one that several cover."""
+    where = where or REFERENCES
+    found = _load(where)
+    cell = {"config": config, "traffic": traffic}
+    fits, lacks = [], []
+    for name, mod in found.items():
+        off = [f"{k} {_field(cell, k)!r}" for k, allowed in
+               mod.IMPLEMENTS.items() if _field(cell, k) not in allowed]
+        if off:
+            lacks.append(f"{name} does not implement {', '.join(off)}; "
+                         f"it has {_has(mod)}")
+        else:
+            fits.append(name)
+    if not fits:
+        raise ValueError(f"no plain reference in {where} replays this "
+                         "cell: " + " | ".join(lacks or ["none found"]))
+    if len(fits) > 1:
+        raise ValueError(f"more than one plain reference in {where} "
+                         f"covers this cell: {', '.join(fits)}")
+    return found[fits[0]]
 
 
 def require_reference(config: dict, traffic: dict) -> None:
-    """Refuse a cell whose semantics the reference does not implement."""
-    asked = {"topology kind": config["topology"]["kind"],
-             "traffic pattern": traffic["pattern"],
-             "route_mode": config["routing"]["route_mode"],
-             "vc_mode": config["routing"]["vc_mode"]}
-    missing = [f"{k} {v!r}" for k, v in asked.items()
-               if v not in SUPPORTED[k]]
-    if missing:
-        raise ValueError("the plain reference (bench/reference.py) does "
-                         "not implement " + ", ".join(missing) +
-                         "; it has " + "; ".join(
-                             f"{k} {'/'.join(v)}" for k, v in
-                             SUPPORTED.items()))
+    """Refuse a cell whose semantics no plain reference implements."""
+    find_reference(config, traffic)
 
 
-def lane_rate(config: dict, traffic: dict, fab: ref.Fabric) -> float:
-    """Per-terminal packet rate of the offered flits/cycle/chip, as the
-    float32 the lanes compare their variates with."""
-    pkt_len = config["routing"]["pkt_len"]
-    return float(np.float32(traffic["offered"] / pkt_len
-                            / (fab.T / fab.chips)))
-
-
-def reference_records(config: dict, traffic: dict, seeds, cycles: int,
+def reference_records(config: dict, traffic: dict, lanes, cycles: int,
                       control: bool = False) -> list:
-    """Each lane replayed by the plain reference to `cycles`."""
-    require_reference(config, traffic)
-    params = dict(config["topology"]["params"])
-    routing = dict(config["routing"], classes=config["vc_classes"])
-    fab = ref.Fabric(**params, pkt_len=routing["pkt_len"])
-    rate = lane_rate(config, traffic, fab)
+    """Each lane, an (offered, seed) pair, replayed to `cycles` by the
+    plain reference that covers the cell."""
+    mod = find_reference(config, traffic)
     dtypes = lower_precision() if control else FULL
-    out = []
-    for seed in seeds:
-        u, d = ref.draws(seed, cycles, fab.T)
-        lane = ref.Lane(fab, vcs_per_class=routing["vcs_per_class"],
-                        classes=routing["classes"],
-                        buf_pkts=routing["buf_pkts"],
-                        srcq_pkts=routing["srcq_pkts"], rate=rate,
-                        warmup=traffic["fill"], stats_dtype=dtypes)
-        for t in range(cycles):
-            lane.step(t, u[t], d[t])
-        out.append(lane.record())
-    return out
+    return mod.replay(config, traffic, list(lanes), cycles, dtypes)
 
 
 def compare(got: list, want: list) -> tuple:

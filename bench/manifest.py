@@ -32,6 +32,23 @@ class Cell:
         kept inside the 31 bits a lane key is made from."""
         return [(seed + off) % 2**31 for off in self.traffic["seed_offsets"]]
 
+    def lane_rates(self) -> list:
+        """The lanes' offered rates, flits/cycle/chip: the traffic's
+        `offered`, one rate for every lane, or a list with one per
+        entry of `seed_offsets`."""
+        offered = self.traffic["offered"]
+        n = len(self.traffic["seed_offsets"])
+        if not isinstance(offered, list):
+            return [float(offered)] * n
+        if len(offered) != n:
+            raise ValueError(f"{self.name}: {len(offered)} offered rates "
+                             f"for {n} seed offsets")
+        return [float(r) for r in offered]
+
+    def lanes(self, seed: int) -> list:
+        """The lanes as (offered, seed) pairs."""
+        return list(zip(self.lane_rates(), self.lane_seeds(seed)))
+
 
 def _read(path: str) -> dict:
     with open(path) as f:
@@ -75,8 +92,10 @@ def metric_reader(name: str, metrics_dir: str = os.path.join(BENCH,
 
 def experiment(cell: Cell, seed: int):
     """The cell as the program's own declarative spec: one topology, one
-    routing, one traffic, one offered rate and the cell's lane seeds.
-    Step form and grant form are left at the program's defaults."""
+    routing, one traffic, the lanes' offered rates and seeds.  The spec's
+    grid is rates x seeds; the session runs the cell's own (rate, seed)
+    pairs (`Cell.lanes`), which that grid holds.  Step form and grant form
+    are left at the program's defaults."""
     from repro.exp import (ExperimentSpec, RoutingSpec, SweepAxes,
                            TopologySpec, TrafficSpec)
     topo = cell.config["topology"]
@@ -87,6 +106,6 @@ def experiment(cell: Cell, seed: int):
                                  tuple(sorted(topo["params"].items()))),),
         traffics=(TrafficSpec(tr["pattern"]),),
         routings=(RoutingSpec(**cell.config["routing"]),),
-        axes=SweepAxes(rates=(tr["offered"],),
+        axes=SweepAxes(rates=tuple(dict.fromkeys(cell.lane_rates())),
                        seeds=tuple(cell.lane_seeds(seed)),
                        warmup=tr["fill"], measure=tr["measure"]))

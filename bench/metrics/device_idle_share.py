@@ -1,6 +1,6 @@
 """device_idle_share: the share of the traced window in which no op ran
-on the device, in percent (1 - busy / window).  Layer: device.  Moves
-lane_cycles_per_s."""
+on a device, in percent (1 - busy / window), the mean over the devices
+that ran lanes.  Layer: device.  Moves lane_cycles_per_s."""
 
 
 def read(run, trace):

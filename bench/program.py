@@ -10,8 +10,9 @@ spans.  The readers in `bench/metrics/` take from here:
                         the program compiled in this run, from its
                         optimized HLO text; None when the program names
                         no phase
-    ms_per_lane_cycle   one phase's device time over the traced
-                        lane-cycles, from the trace's per-op totals
+    ms_per_lane_cycle   one phase's device time, summed over the devices,
+                        over the traced lane-cycles, from the trace's
+                        per-op totals
     span_totals()       the program's host-span seconds by name; None
                         when the program has no spans
 
@@ -121,8 +122,9 @@ def window_ops():
 
 def phase_seconds(trace, ops):
     """{phase: device seconds} of the traced ops that `ops` maps, plus
-    `unscoped` for those under no phase, averaged over the devices; None
-    without a trace, a map, or a traced op of the mapped program."""
+    `unscoped` for those under no phase, summed over the devices as the
+    trace's op totals are; None without a trace, a map, or a traced op of
+    the mapped program."""
     if not trace or not ops:
         return None
     per = defaultdict(float)
@@ -132,7 +134,7 @@ def phase_seconds(trace, ops):
             per[ops[key] or UNSCOPED] += s
     if not per:
         return None
-    return {k: v / max(trace.get("devices", 1), 1) for k, v in per.items()}
+    return dict(per)
 
 
 def ms_per_lane_cycle(phase: str, run, trace):

@@ -1,4 +1,4 @@
-"""Plain reference of the simulated fabric, written apart from the program.
+"""Plain reference of the switch-less fabric under minimal routing.
 
 It re-implements, in straightforward numpy and from the fabric's published
 description (arXiv:2407.10290, Sec. III-IV), one lane of the flit-level
@@ -23,8 +23,10 @@ shares with the program is only the input: the traffic each lane offers is
 drawn with `jax.random` from the lane's seed (`draws`), cycle by cycle, as
 the cell's traffic defines it.
 
-`Lane.step` is the whole cycle.  `stats_dtype` lets the control of the
-correctness check (`bench.check`) keep the statistics one precision lower.
+`IMPLEMENTS` declares the cells it covers (`bench/check.py` finds it by
+that), and `replay` is its entry point.  `Lane.step` is the whole cycle.
+`stats_dtype` lets the control of the correctness check keep the
+statistics one precision lower.
 """
 from __future__ import annotations
 
@@ -35,6 +37,16 @@ NUM_TYPES = 5
 DIRS = ((0, -1), (1, 0), (0, 1), (-1, 0))      # N, E, S, W as (dx, dy)
 COUNTERS = ("generated", "delivered", "dropped", "stranded", "reaped",
             "occ_peak")
+
+# the cells this reference replays: each dotted path into the cell's
+# {"config": ..., "traffic": ...} and the values it implements there (None:
+# the path is absent, as on a pristine fabric with no fault set)
+IMPLEMENTS = {"config.topology.kind": ("switchless",),
+              "traffic.pattern": ("uniform",),
+              "config.routing.route_mode": ("min",),
+              "config.routing.vc_mode": ("baseline",),
+              "config.faults": (None,),
+              "traffic.faults": (None,)}
 
 
 def perimeter(R: int) -> list:
@@ -357,3 +369,32 @@ class Lane:
         rec["ch_busy"] = self.ch_busy.copy()
         return rec
 
+
+
+def lane_rate(config: dict, offered: float, fab: Fabric) -> float:
+    """Per-terminal packet rate of `offered` flits/cycle/chip, as the
+    float32 the lanes compare their variates with."""
+    pkt_len = config["routing"]["pkt_len"]
+    return float(np.float32(offered / pkt_len / (fab.T / fab.chips)))
+
+
+def replay(config: dict, traffic: dict, lanes, cycles: int,
+           stats_dtype) -> list:
+    """Each lane, given as (offered flits/cycle/chip, seed), replayed from
+    an empty fabric to `cycles`, with its statistics in `stats_dtype`
+    (integer type, float type); one `Lane.record()` per lane."""
+    routing = config["routing"]
+    fab = Fabric(**config["topology"]["params"], pkt_len=routing["pkt_len"])
+    out = []
+    for offered, seed in lanes:
+        u, d = draws(seed, cycles, fab.T)
+        lane = Lane(fab, vcs_per_class=routing["vcs_per_class"],
+                    classes=config["vc_classes"],
+                    buf_pkts=routing["buf_pkts"],
+                    srcq_pkts=routing["srcq_pkts"],
+                    rate=lane_rate(config, offered, fab),
+                    warmup=traffic["fill"], stats_dtype=stats_dtype)
+        for t in range(cycles):
+            lane.step(t, u[t], d[t])
+        out.append(lane.record())
+    return out

@@ -110,7 +110,7 @@ def open_session(cell, sweep, seed: int, devs):
     if tr["fill"] % tr["window"]:
         refuse(f"{cell.name}: fill {tr['fill']} is not a whole number of "
                f"{tr['window']}-cycle windows")
-    lanes = [(tr["offered"], s, None) for s in cell.lane_seeds(seed)]
+    lanes = [(rate, s, None) for rate, s in cell.lanes(seed)]
     # a one-chip cell on a larger host stays on one chip
     device = devs[0] if cell.chips == 1 and len(devs) > 1 else None
     return sweep.start_lanes(lanes, window=tr["window"], device=device)
@@ -219,6 +219,7 @@ def main(argv=None, *, manifest_path=manifest.MANIFEST,
     args = parse(argv)
     cell = manifest.load_cell(args.workload, manifest_path)
     try:
+        cell.lanes(args.seed)
         check.require_reference(cell.config, cell.traffic)
     except ValueError as e:
         refuse(f"{cell.name}: {e}")
@@ -234,7 +235,8 @@ def main(argv=None, *, manifest_path=manifest.MANIFEST,
     fill_s = run_fill(sess)
     setup_s = time.perf_counter() - T_START
     say(f"set-up {setup_s:.3f} s: compile {sess.compile_s:.3f} s, fill "
-        f"{fill_s:.3f} s to cycle {sess.cycle}")
+        f"{fill_s:.3f} s to cycle {sess.cycle}; placement {sess.placement}, "
+        f"step {sess.step_impl}, grant {sess.grant_form}")
 
     trace_dir = tempfile.mkdtemp(prefix="bench-trace-") if args.trace else None
     compiles = count_compiles()
@@ -265,11 +267,12 @@ def main(argv=None, *, manifest_path=manifest.MANIFEST,
         f"{peak} B; executable {exe}; memory {used[0].memory_stats()}")
 
     t = time.perf_counter()
-    want = check.reference_records(cell.config, cell.traffic,
-                                   cell.lane_seeds(args.seed), cycles)
+    lane_pairs = cell.lanes(args.seed)
+    want = check.reference_records(cell.config, cell.traffic, lane_pairs,
+                                   cycles)
     numbers, failed = check.compare(got, want)
-    correct = check.verdict(numbers) and windows >= 1 and len(got) == len(
-        cell.lane_seeds(args.seed))
+    correct = (check.verdict(numbers) and windows >= 1
+               and len(got) == len(want) == len(lane_pairs))
     say(f"reference replayed {lanes} lanes to cycle {cycles} in "
         f"{time.perf_counter() - t:.1f} s")
 

@@ -1,5 +1,6 @@
 """The harness driven end to end on the CPU rehearsal cell, and the cells
 of BENCHMARK.json read by name."""
+import dataclasses
 import json
 import os
 import shutil
@@ -8,12 +9,13 @@ import sys
 
 import pytest
 
-from bench import manifest, run
+from bench import check, manifest, run
 from bench import trace as tr
 
 HERE = os.path.dirname(os.path.abspath(__file__))
 REHEARSAL = os.path.join(HERE, "rehearsal", "BENCHMARK.json")
 FIXTURE = os.path.join(HERE, "data", "fixture.xplane.pb")
+SRC = os.path.join(manifest.ROOT, "src")
 KEYS = ["correct", "attempted", "failed", "metrics", "device"]
 
 
@@ -35,12 +37,18 @@ def test_every_cell_found_by_name():
         assert {"pattern", "offered", "lanes", "seed_offsets", "fill",
                 "window", "measure"} <= set(cell.traffic)
         assert len(cell.lane_seeds(2**33)) == cell.traffic["lanes"]
+        assert len(cell.lanes(2**33)) == cell.traffic["lanes"]
         text = json.dumps([cell.config, cell.traffic])
         for knob in ("REPRO_", "step_impl", "grant_impl"):
             assert knob not in text
-        # the program builds the cell's spec from the files alone
+        # the program builds the cell's spec from the files alone; its
+        # rates x seeds grid holds every lane of the cell
         spec = manifest.experiment(cell, 2**33 + 5)
-        assert spec.axes.lanes_per_grid == cell.traffic["lanes"]
+        assert spec.axes.seeds == tuple(cell.lane_seeds(2**33 + 5))
+        assert set(spec.axes.rates) == set(cell.lane_rates())
+        assert len(spec.axes.rates) == len(set(cell.lane_rates()))
+        # and a plain reference replays it
+        check.require_reference(cell.config, cell.traffic)
     with pytest.raises(KeyError):
         manifest.load_cell("no-such-cell")
 
@@ -111,3 +119,34 @@ def test_refuses_without_the_program(tmp_path):
     assert p.returncode != 0
     assert p.stdout == ""
     assert "no program" in p.stderr
+
+
+def test_lane_rates_one_or_one_per_lane():
+    cell = manifest.load_cell("tiny.uniform", REHEARSAL)
+    assert cell.lanes(5) == [(1.0, 5), (1.0, 6)]
+    per_lane = manifest.load_cell("tiny.uniform-4lane", REHEARSAL)
+    assert per_lane.lanes(2**31 - 1) == [(0.4, 2**31 - 1), (0.4, 0),
+                                         (0.8, 1), (0.8, 2)]
+    short = dataclasses.replace(per_lane, traffic=dict(
+        per_lane.traffic, offered=[0.4, 0.8]))
+    with pytest.raises(ValueError, match="2 offered rates for 4"):
+        short.lanes(1)
+
+
+def test_four_lane_cell_on_four_host_devices():
+    """A four-chip cell on four CPU devices: one lane per device through
+    the program's lane shard_map, every lane correct."""
+    code = (f"import sys; sys.path[:0] = [{manifest.ROOT!r}, {SRC!r}]\n"
+            "from bench import run\n"
+            "sys.exit(run.main(['--workload', 'tiny.uniform-4lane', "
+            "'--seed', '2147483653', '--seconds', '0.5', '--trace', '0'], "
+            f"manifest_path={REHEARSAL!r}, require_tpu=False))")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    p = subprocess.run([sys.executable, "-c", code], cwd=manifest.ROOT,
+                       capture_output=True, text=True, timeout=300, env=env)
+    assert p.returncode == 0, p.stderr[-3000:]
+    line = json.loads(p.stdout.strip().splitlines()[-1])
+    assert line["correct"] is True and line["attempted"] == 4
+    assert line["device"]["count"] == 4
+    assert "placement lanes:4," in p.stderr

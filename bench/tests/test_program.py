@@ -91,8 +91,9 @@ def test_phase_seconds_attributes_only_the_mapped_program():
     red = {"devices": 2, "op_totals": {
         "%fusion.1": 4.0, "%scatter.2": 2.0, "%copy.3": 1.0,
         "%reduce.9": 8.0}}          # another program's op: not attributed
+    # device-seconds, summed over the two devices as busy_sum_s is
     assert program.phase_seconds(red, ops) == {
-        "grant": 2.0, "apply": 1.0, "unscoped": 0.5}
+        "grant": 4.0, "apply": 2.0, "unscoped": 1.0}
     assert program.phase_seconds(red, None) is None
     assert program.phase_seconds(None, ops) is None
     assert program.phase_seconds({"devices": 1, "op_totals": {

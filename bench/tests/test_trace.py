@@ -5,6 +5,7 @@ import os
 
 import pytest
 
+from bench import manifest
 from bench import trace as tr
 from bench.trace import Event
 
@@ -100,3 +101,33 @@ def test_recorded_tpu_trace():
     assert red["busy_s"] <= sum(known["traced_block_s"])
     assert red["busy_s"] >= 0.8 * known["runs"] * known["untraced_run_s"]
     assert hi - lo >= known["runs"] * known["sleep_s"]
+
+
+def test_devices_sum_for_device_time_and_average_for_idle():
+    """Two devices that ran lanes, 6 s and 4 s busy, and one that ran
+    nothing: device time per lane-cycle is their sum, as the per-op totals
+    are; the idle share is their mean; the idle device counts in neither."""
+    dev1 = "/device:TPU:1"
+    evs = synthetic() + [ev(dev1, tr.OPS_LINE, "fusion.1", 2.0, 6.0),
+                         ev("/device:TPU:2", tr.MODULES_LINE, "jit_x", 1, 2)]
+    red = tr.reduce(evs)
+    assert red["devices"] == 2
+    assert red["busy_s"] == pytest.approx(5.0)
+    assert red["busy_sum_s"] == pytest.approx(10.0)
+    assert sum(red["op_totals"].values()) == pytest.approx(
+        red["busy_sum_s"] + 1.0)          # the overlap on device 0
+    run = {"lane_cycles_traced": 20}
+    read = manifest.metric_reader
+    assert read("device_ms_per_lane_cycle")(run, red) == pytest.approx(500.0)
+    assert read("device_idle_share")(run, red) == pytest.approx(50.0)
+
+
+def test_one_device_reads_alike_summed_or_averaged():
+    """On one device, the recorded TPU trace, the sum over devices is the
+    mean: device time per lane-cycle reads what it read as a mean."""
+    red = tr.reduce(tr.load(FIXTURE))
+    assert red["devices"] == 1
+    assert red["busy_sum_s"] == red["busy_s"]
+    run = {"lane_cycles_traced": 30}
+    assert manifest.metric_reader("device_ms_per_lane_cycle")(run, red) == \
+        1e3 * red["busy_s"] / 30

@@ -4,19 +4,24 @@
 returns, over the traced window (the host span `bench.traced`):
 
     window       (start, end) of the traced window, seconds
-    busy_s       device busy time: the union of the device's leaf op
-                 intervals inside the window, averaged over the devices (a
-                 control-flow op such as the scan's `while` encloses the ops
-                 it runs, and would hide the gaps between them)
-    gaps         idle intervals (start, end) of the first device inside the
-                 window
+    devices      how many devices ran an op inside the window (all the
+                 trace's devices where none did)
+    busy_s       device busy time: the union of a device's leaf op
+                 intervals inside the window, averaged over those devices
+                 (a control-flow op such as the scan's `while` encloses the
+                 ops it runs, and would hide the gaps between them)
+    busy_sum_s   the same union summed over those devices: device-seconds
+    gaps         idle intervals (start, end) of the first of those devices
+                 inside the window
     spans        the `bench.*` host spans, which `breakdown` labels the
                  longest gaps with
     module_gaps  time between consecutive runs of the main program (the one
-                 with the most device time) on the first device: one
+                 with the most device time) on that first device: one
                  window's end to the next one's start, seconds
-    op_totals    device seconds per leaf op, summed over devices; an op is
-                 named by its HLO instruction (`%fusion.12`)
+    op_totals    device seconds per leaf op, summed over the devices, so
+                 that they add up to `busy_sum_s` where ops do not
+                 overlap; an op is named by its HLO instruction
+                 (`%fusion.12`)
 
 A device plane is one named `/device:<KIND>:<n>`; its ops are the events of
 the line `XLA Ops`, its program executions those of `XLA Modules`.  Host
@@ -138,10 +143,12 @@ def reduce(events: list) -> dict:
             s, t = max(e.start, lo), min(e.end, hi)
             if t > s:
                 totals[e.name] += t - s
-    first = busy[devices[0]]
+    used = [d for d in devices if busy[d]] or devices
+    busy_sum = sum(sum(e - s for s, e in busy[d]) for d in used)
+    first = busy[used[0]]
     edges = [lo] + [x for iv in first for x in iv] + [hi]
     gaps = [(s, e) for s, e in zip(edges[0::2], edges[1::2]) if e > s]
-    mods = [e for e in events if e.plane == devices[0]
+    mods = [e for e in events if e.plane == used[0]
             and e.line == MODULES_LINE and e.end > lo and e.start < hi]
     per = defaultdict(float)
     for e in mods:
@@ -151,9 +158,9 @@ def reduce(events: list) -> dict:
     module_gaps = [max(b[0] - a[1], 0.0) for a, b in zip(main, main[1:])]
     return {
         "window": (lo, hi),
-        "devices": len(devices),
-        "busy_s": sum(sum(e - s for s, e in busy[d]) for d in devices)
-        / len(devices),
+        "devices": len(used),
+        "busy_s": busy_sum / len(used),
+        "busy_sum_s": busy_sum,
         "gaps": gaps,
         "spans": spans,
         "modules": len(main),
